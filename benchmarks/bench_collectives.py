@@ -1,5 +1,9 @@
 """§Perf bench: SeqBalance multi-path grad sync vs stock XLA all-reduce —
-collective op counts/bytes from lowered HLO on an 8-device subprocess."""
+collective op counts/bytes from lowered HLO on an 8-device subprocess.
+
+The child only counts HLO bytes on 8 virtual CPU devices, so it runs with
+``JAX_PLATFORMS=cpu``: it must never try to open a TPU that the parent
+process already holds."""
 from __future__ import annotations
 
 import json
@@ -41,15 +45,13 @@ def bench_collectives(fast=True):
     for wire in ("float32", "bfloat16"):
         env = dict(os.environ)
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = SRC
         r = subprocess.run([sys.executable, "-c", _CODE % wire], capture_output=True,
                            text=True, env=env, timeout=600)
         if r.returncode != 0:
-            # a crashed subprocess may die before writing anything to stderr
-            err_lines = r.stderr.strip().splitlines()
-            why = err_lines[-1][:80] if err_lines else f"exit_{r.returncode}_no_stderr"
-            emit(f"collectives_{wire}", 0.0, "FAILED_" + why)
-            continue
+            raise RuntimeError(f"collectives_{wire} child exited {r.returncode}:"
+                               f"\n{r.stderr[-2000:]}")
         res = json.loads(r.stdout.strip().splitlines()[-1])
         sb, bl = res["seqbalance"], res["baseline"]
         emit(f"collectives_seqbalance_{wire}", 0.0,

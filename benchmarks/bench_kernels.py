@@ -1,17 +1,18 @@
-"""Kernel micro-benches: wall time of the jnp reference path on CPU (the
-Pallas kernels target TPU; interpret mode is a correctness harness, so the
-derived column reports ref-path throughput + kernel/ref agreement)."""
+"""Kernel micro-benches: wall time of each Pallas kernel's jnp reference
+and the kernel/reference agreement.  On the TPU the kernel is the compiled
+one; on the CPU backend it runs in the Pallas interpreter (a correctness
+harness only), and the row names say ``interpret``."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from benchmarks.common import emit, timed
-from repro.kernels import flash_attention as fa, linkload as ll, ref
+from repro.kernels import ops, ref
 
 
 def bench_kernels(fast=True):
+    mode = "interpret" if ops.interpret_mode() else "compiled"
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     B, S, H, K, hd = 2, 512, 8, 2, 64
     q = jax.random.normal(ks[0], (B, S, H, hd), jnp.float32)
@@ -21,9 +22,9 @@ def bench_kernels(fast=True):
     f(q, k, v).block_until_ready()
     _, us = timed(lambda: f(q, k, v).block_until_ready(), repeat=5)
     flops = 4 * B * H * S * S * hd / 2
-    o1 = fa.flash_attention(q, k, v, block_q=128, block_k=128, interpret=True)
+    o1 = ops.flash_attention(q, k, v, block_q=128, block_k=128)
     err = float(jnp.max(jnp.abs(o1 - f(q, k, v))))
-    emit("kernel_flash_attention_ref", us,
+    emit(f"kernel_flash_attention_ref_vs_{mode}", us,
          f"{flops/us/1e3:.1f}GFLOPs_kernel_maxerr_{err:.1e}")
 
     n, L = 8192, 512
@@ -34,6 +35,7 @@ def bench_kernels(fast=True):
     g = jax.jit(lambda: ref.linkload_ref(lid, rates, L, 400e3, 1600e3, 0.2, queue, cap, 1e-5))
     g()[0].block_until_ready()
     _, us = timed(lambda: g()[0].block_until_ready(), repeat=10)
-    l1, _, _ = ll.linkload(lid, rates, queue, cap, n_links=L, interpret=True)
+    l1, _, _ = ops.linkload(lid, rates, queue, cap, n_links=L)
     err = float(jnp.max(jnp.abs(l1 - g()[0])))
-    emit("kernel_linkload_ref", us, f"{n*6/us:.0f}Mupdates/s_kernel_maxerr_{err:.1e}")
+    emit(f"kernel_linkload_ref_vs_{mode}", us,
+         f"{n*6/us:.0f}Mupdates/s_kernel_maxerr_{err:.1e}")

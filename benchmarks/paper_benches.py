@@ -313,7 +313,7 @@ def bench_netsim_speedup(fast=True):
         stat_diff_pct={k: round(v, 4) for k, v in diffs.items()},
     )
     # reproducibility: how the sweep was dispatched on this machine
-    from repro.netsim import dataplane
+    from repro.netsim import compile_cache, dataplane
 
     PERF["sweep_config"] = dict(
         workers=sweep.default_workers(len(schemes)),
@@ -321,7 +321,7 @@ def bench_netsim_speedup(fast=True):
         devices=sweep.sweep_devices(),
         # persistent XLA compile cache: the recorded sweep is warm from the
         # second process on (production sweeps relaunch identical programs)
-        compile_cache=sweep.enable_compile_cache() or "disabled",
+        compile_cache=compile_cache.enable_compile_cache(),
     )
 
 

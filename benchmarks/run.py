@@ -5,7 +5,8 @@ artifacts) and writes a machine-readable BENCH_netsim.json (CSV rows plus
 the netsim perf records from benchmarks/common.PERF: per-step µs, sweep
 wall-clock, compact-vs-dense speedup).  ``--full`` switches to paper-scale
 simulation parameters; ``--only <substr>`` filters benches; ``--json ''``
-disables the JSON dump.
+disables the JSON dump.  A bench that raises does not stop the others,
+but the run then exits non-zero.
 """
 from __future__ import annotations
 
@@ -13,6 +14,7 @@ import argparse
 import json
 import sys
 import time
+import traceback
 
 
 def main() -> None:
@@ -42,14 +44,17 @@ def main() -> None:
         benches.append(paper_benches.bench_profile_phases)
     print("name,us_per_call,derived")
     t0 = time.time()
+    failed = []
     for b in benches:
         if args.only and args.only not in b.__name__:
             continue
         try:
             b(fast=not args.full)
-        except Exception as e:  # a failed bench must not hide the others
+        except Exception as e:  # run the other benches, then fail the run
+            failed.append(b.__name__)
             print(f"{b.__name__},0.0,ERROR_{type(e).__name__}:_{str(e)[:120]}",
                   file=sys.stdout, flush=True)
+            traceback.print_exc()
     wall = time.time() - t0
     print(f"# total_wall_s,{wall:.1f},", flush=True)
 
@@ -72,6 +77,8 @@ def main() -> None:
             print(f"# wrote {args.json}", flush=True)
         except OSError as e:  # never lose a long bench run to a bad path
             print(f"# could not write {args.json}: {e}", file=sys.stderr)
+    if failed:
+        sys.exit(f"# {len(failed)} bench(es) failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -3,6 +3,10 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
+# CI is a CPU run (interpret-mode kernels, forced host devices); the chip
+# run is chip_smoke.py.  Pinning the backend also keeps the subprocesses
+# the tests start off any TPU their parent would hold.
+export JAX_PLATFORMS=cpu
 
 python -m pytest -x -q --durations=15
 

@@ -17,9 +17,4 @@ not reorder.  This package supplies that side of the reproduction:
     paths, per-epoch FCT/imbalance/plan-churn land in a CosimHistory, and
     link capacity rides through the sweep as a traced operand so every
     epoch reuses one compiled program (the Fig. 11 convergence story).
-
-Importing the package installs the jax 0.4.x forward-compat shims
-(``_compat``) so the modern sharding API the modules are written against
-resolves on the pinned toolchain.
 """
-from repro.dist import _compat  # noqa: F401  (installs jax API shims)

@@ -22,8 +22,6 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from repro.dist import _compat  # noqa: F401  (jax API shims)
-
 
 @dataclasses.dataclass(frozen=True)
 class PathPlan:

@@ -19,8 +19,6 @@ import numpy as np
 from jax import tree as jtree
 from jax.sharding import PartitionSpec as P
 
-from repro.dist import _compat  # noqa: F401  (jax API shims)
-
 
 def _axis_sizes(mesh) -> dict:
     return dict(mesh.shape)

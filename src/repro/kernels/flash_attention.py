@@ -43,10 +43,8 @@ def _flash_kernel(
 
     def body(ki, carry):
         m, l, acc = carry
-        # NOTE: the leading singleton must be a dslice — a bare int here
-        # breaks the interpret-mode load discharge (no .shape on int).
-        k = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(ki * block_k, block_k), slice(None)))[0]
-        v = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(ki * block_k, block_k), slice(None)))[0]
+        k = k_ref[0, pl.ds(ki * block_k, block_k), :]
+        v = v_ref[0, pl.ds(ki * block_k, block_k), :]
         s = jnp.dot(q, k.astype(jnp.float32).T)  # [block_q, block_k]
         if softcap:
             s = softcap * jnp.tanh(s / softcap)

@@ -1,18 +1,31 @@
-"""Pallas TPU kernel for the switch dataplane step (netsim hot-spot).
+"""Pallas TPU kernels for the switch dataplane step (netsim hot-spot).
 
 Computes per-link offered load from (sub-flow -> link) incidence plus the
 queue update and RED/ECN mark probabilities — the per-step work of every
 ToR/spine in the fluid simulator.
 
 TPU adaptation: the scatter-add over link ids is reformulated as a
-ONE-HOT MATMUL so it runs on the MXU instead of serial scatter ports:
-sub-flows stream through the grid in ``block_n`` tiles; for each tile the
-kernel builds onehot[block_n, n_links] via broadcasted_iota comparison and
-accumulates ``rates @ onehot`` into a VMEM-resident load vector.  Queue
-and mark updates fuse into the final grid step (revisiting HBM zero
-times).  n_links is padded to lanes (128).
+ONE-HOT MATMUL so it runs on the MXU instead of serial scatter ports.
+Sub-flows stream through the grid in ``block_n`` tiles with the flow axis
+on the 128-wide lane axis; for each tile the kernel builds a TRANSPOSED
+one-hot ``oh[L_pad, block_n]`` (links on sublanes) by a broadcasted_iota
+comparison.  Then
 
-Oracle: kernels/ref.py::linkload_ref (segment_sum formulation).
+  * load  = rates[1, bn] . oh^T  -> [1, L_pad]   (scatter-add)
+  * gather = scale[1, L_pad] @ oh -> [1, bn]      (per-flow link value)
+
+Every operand is 2-D, every per-link vector is a ``[1, L_pad]`` row and
+every per-flow vector a ``[rows, n]`` array blocked on lanes, so each
+block matches XLA's tiling.  The dots run at ``Precision.HIGHEST``: rates
+are f32 bps near 1e11, and a single bf16 pass would round them on the chip
+only (the one-hot side is exact in any precision).  ``n_links`` is padded
+to lanes (128) with one spare column, the sentinel for absent hops.
+
+VMEM: the one-hot and its iota are ``L_pad * block_n * 4`` bytes each;
+``block_n=256`` keeps them at 2.2 MB at ``three_tier()`` width (2080
+links), inside the default scoped-VMEM limit.
+
+Oracles: kernels/ref.py::linkload_ref / linkload_cascade_tiered_ref.
 """
 from __future__ import annotations
 
@@ -23,36 +36,65 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _onehot(lid_row: jax.Array, n_links_padded: int) -> jax.Array:
+    """f32[L_pad, bn]: oh[l, i] = (lid_row[0, i] == l).  lid_row i32[1, bn]."""
+    iota = jax.lax.broadcasted_iota(
+        jnp.int32, (n_links_padded, lid_row.shape[-1]), 0)
+    return (iota == lid_row).astype(jnp.float32)
+
+
+def _scatter(r_row: jax.Array, oh: jax.Array) -> jax.Array:
+    """[1, bn] x [L_pad, bn] -> [1, L_pad]: per-link sum of the row."""
+    return jax.lax.dot_general(
+        r_row, oh, (((1,), (1,)), ((), ())), precision=_HI,
+        preferred_element_type=jnp.float32)
+
+
+def _gather(s_row: jax.Array, oh: jax.Array) -> jax.Array:
+    """[1, L_pad] @ [L_pad, bn] -> [1, bn]: each flow's link value."""
+    return jnp.dot(s_row, oh, precision=_HI, preferred_element_type=jnp.float32)
+
+
+def _red_mark(newq, kmin, kmax, pmax):
+    ramp = (newq - kmin) / (kmax - kmin)
+    return jnp.where(newq < kmin, 0.0, jnp.where(newq > kmax, 1.0, ramp * pmax))
+
+
+def _row(x: jax.Array, width: int, fill: float = 0.0) -> jax.Array:
+    """f32[n] -> f32[1, width] (padded with ``fill``)."""
+    x = x.astype(jnp.float32)
+    return jnp.pad(x, (0, width - x.shape[0]), constant_values=fill)[None, :]
+
+
+def _pad_links(n_links: int) -> int:
+    return ((n_links + 1 + 127) // 128) * 128
+
 
 def _linkload_kernel(
     lid_ref, rate_ref, queue_ref, cap_ref, load_ref, newq_ref, mark_ref,
-    *, n_links_padded, hops, kmin, kmax, pmax, dt,
+    *, n_links_padded, kmin, kmax, pmax, dt,
 ):
     ti = pl.program_id(0)
-    n_tiles = pl.num_programs(0)
 
     @pl.when(ti == 0)
     def _init():
         load_ref[...] = jnp.zeros_like(load_ref)
 
-    lids = lid_ref[...]  # [block_n, hops] i32 (-1 = none)
-    rates = rate_ref[...]  # [block_n]
-    contrib = jnp.broadcast_to(rates[:, None], lids.shape).reshape(-1)  # [bn*hops]
-    flat = lids.reshape(-1)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (flat.shape[0], n_links_padded), 1)
-    onehot = (iota == flat[:, None]).astype(jnp.float32)  # MXU-friendly
-    load_ref[...] += contrib @ onehot  # [n_links_padded]
+    lids = lid_ref[...]  # [hops, block_n] i32 (-1 = none: matches no row)
+    rates = rate_ref[...]  # [1, block_n]
+    for h in range(lids.shape[0]):
+        load_ref[...] += _scatter(rates, _onehot(lids[h:h + 1], n_links_padded))
 
-    @pl.when(ti == n_tiles - 1)
+    @pl.when(ti == pl.num_programs(0) - 1)
     def _finalize():
         load = load_ref[...]
-        q = queue_ref[...]
-        cap = cap_ref[...]
-        newq = jnp.clip(q + (load - cap) * dt / 8.0, 0.0, 8e6)
-        ramp = (newq - kmin) / (kmax - kmin)
-        mark = jnp.where(newq < kmin, 0.0, jnp.where(newq > kmax, 1.0, ramp * pmax))
+        newq = jnp.clip(queue_ref[...] + (load - cap_ref[...]) * dt / 8.0,
+                        0.0, 8e6)
         newq_ref[...] = newq
-        mark_ref[...] = mark
+        mark_ref[...] = _red_mark(newq, kmin, kmax, pmax)
 
 
 @functools.partial(
@@ -69,214 +111,125 @@ def linkload(
     kmax: float = 1600e3,
     pmax: float = 0.2,
     dt: float = 10e-6,
-    block_n: int = 512,
+    block_n: int = 256,
     interpret: bool = False,
 ):
+    """(load, new_queue, mark) of the ToR/spine step; oracle ref.linkload_ref."""
     n, hops = link_ids.shape
     pad_n = (-n) % block_n
-    if pad_n:
-        link_ids = jnp.pad(link_ids, ((0, pad_n), (0, 0)), constant_values=-1)
-        rates = jnp.pad(rates, (0, pad_n))
-    L_pad = ((n_links + 127) // 128) * 128
-    queue_p = jnp.pad(queue, (0, L_pad - n_links))
-    cap_p = jnp.pad(capacity[:n_links], (0, L_pad - n_links), constant_values=1e30)
-
-    grid = ((n + pad_n) // block_n,)
+    lid_t = jnp.pad(link_ids.astype(jnp.int32), ((0, pad_n), (0, 0)),
+                    constant_values=-1).T  # [hops, n_pad]
+    rates_r = jnp.pad(rates.astype(jnp.float32), (0, pad_n))[None, :]
+    L_pad = _pad_links(n_links)
+    row = pl.BlockSpec((1, L_pad), lambda t: (0, 0))
     load, newq, mark = pl.pallas_call(
         functools.partial(
             _linkload_kernel,
-            n_links_padded=L_pad, hops=hops, kmin=kmin, kmax=kmax, pmax=pmax, dt=dt,
+            n_links_padded=L_pad, kmin=kmin, kmax=kmax, pmax=pmax, dt=dt,
         ),
-        grid=grid,
+        grid=((n + pad_n) // block_n,),
         in_specs=[
-            pl.BlockSpec((block_n, hops), lambda t: (t, 0)),
-            pl.BlockSpec((block_n,), lambda t: (t,)),
-            pl.BlockSpec((L_pad,), lambda t: (0,)),
-            pl.BlockSpec((L_pad,), lambda t: (0,)),
+            pl.BlockSpec((hops, block_n), lambda t: (0, t)),
+            pl.BlockSpec((1, block_n), lambda t: (0, t)),
+            row, row,
         ],
-        out_specs=[
-            pl.BlockSpec((L_pad,), lambda t: (0,)),
-            pl.BlockSpec((L_pad,), lambda t: (0,)),
-            pl.BlockSpec((L_pad,), lambda t: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((L_pad,), jnp.float32),
-            jax.ShapeDtypeStruct((L_pad,), jnp.float32),
-            jax.ShapeDtypeStruct((L_pad,), jnp.float32),
-        ],
+        out_specs=[row, row, row],
+        out_shape=[jax.ShapeDtypeStruct((1, L_pad), jnp.float32)] * 3,
         interpret=interpret,
-    )(link_ids, rates, queue_p, cap_p)
-    return load[:n_links], newq[:n_links], mark[:n_links]
-
-
-def _cascade_kernel(
-    lid_ref, rate_ref, queue_ref, cap_ref, qmask_ref,
-    arrival_ref, newq_ref, mark_ref, scales_ref, thr_ref, r_ref,
-    *, n_links_padded, hops, kmin, kmax, pmax, dt, qmax,
-):
-    """Fused hop cascade (netsim/dataplane.py).  Grid = (hops + 1, n_tiles),
-    hop-major: pass ``h`` accumulates hop-h offered load over all flow tiles
-    (one-hot matmul) into scales_ref[h], whose last tile converts it in place
-    to the hop's capacity scale.  Each pass first advances the running
-    per-flow rate (scratch ``r_ref``) by the PREVIOUS hop's scale — a second
-    one-hot matmul doubling as the gather — so no hop ever re-reads HBM.
-    The extra final pass (h == hops) applies the last scale to the rates
-    (-> thr) and fuses the queue + RED mark update."""
-    h = pl.program_id(0)
-    t = pl.program_id(1)
-    n_tiles = pl.num_programs(1)
-
-    lids = lid_ref[...]  # [block_n, hops] i32 (sentinel = dummy column)
-    bn = lids.shape[0]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (bn, n_links_padded), 1)
-    hop_iota = jax.lax.broadcasted_iota(jnp.int32, (bn, hops), 1)
-
-    @pl.when((h == 0) & (t == 0))
-    def _init():
-        arrival_ref[...] = jnp.zeros_like(arrival_ref)
-
-    # rate entering hop h = stored rate scaled by hop h-1 (one-hot gather)
-    hprev = jnp.maximum(h - 1, 0)
-    lid_prev = jnp.sum(jnp.where(hop_iota == hprev, lids, 0), axis=1)  # [bn]
-    srow = pl.load(scales_ref, (pl.dslice(hprev, 1), slice(None)))[0]
-    oh_prev = (iota == lid_prev[:, None]).astype(jnp.float32)
-    stored = pl.load(r_ref, (pl.dslice(t, 1), slice(None)))[0]
-    r = jnp.where(h == 0, rate_ref[...], stored * (oh_prev @ srow))
-    pl.store(r_ref, (pl.dslice(t, 1), slice(None)), r[None])
-
-    @pl.when(h < hops)
-    def _accumulate():
-        lid_h = jnp.sum(jnp.where(hop_iota == h, lids, 0), axis=1)
-        oh = (iota == lid_h[:, None]).astype(jnp.float32)
-        acc = pl.load(scales_ref, (pl.dslice(h, 1), slice(None)))[0]
-        acc = jnp.where(t == 0, 0.0, acc)
-        pl.store(scales_ref, (pl.dslice(h, 1), slice(None)), (acc + r @ oh)[None])
-
-    @pl.when((h < hops) & (t == n_tiles - 1))
-    def _finalize_hop():
-        load = pl.load(scales_ref, (pl.dslice(h, 1), slice(None)))[0]
-        arrival_ref[...] += load
-        scale = jnp.minimum(1.0, cap_ref[...] / jnp.maximum(load, 1.0))
-        pl.store(scales_ref, (pl.dslice(h, 1), slice(None)), scale[None])
-
-    @pl.when(h == hops)
-    def _write_thr():
-        thr_ref[...] = r
-
-    @pl.when((h == hops) & (t == n_tiles - 1))
-    def _finalize():
-        arr = arrival_ref[...]
-        newq = jnp.clip(queue_ref[...] + (arr - cap_ref[...]) * dt / 8.0, 0.0, qmax)
-        newq = newq * qmask_ref[...]
-        ramp = (newq - kmin) / (kmax - kmin)
-        mark = jnp.where(newq < kmin, 0.0, jnp.where(newq > kmax, 1.0, ramp * pmax))
-        newq_ref[...] = newq
-        mark_ref[...] = mark
+    )(lid_t, rates_r, _row(queue, L_pad), _row(capacity[:n_links], L_pad, 1e30))
+    return load[0, :n_links], newq[0, :n_links], mark[0, :n_links]
 
 
 def _cascade_tiered_kernel(
     fab_ref, tx_ref, rx_ref, rate_ref, queue_ref, cap_ref, qmask_ref,
-    arrival_ref, newq_ref, mark_ref, scales_ref, thr_ref, r_ref,
-    *, n_links_padded, n_sub, hf, kmin, kmax, pmax, dt, qmax,
+    arrival_ref, newq_ref, mark_ref, thr_ref, scales_ref, r_ref,
+    *, n_links_padded, hf, kmin, kmax, pmax, dt, qmax,
 ):
     """NIC-tiered cascade (netsim/dataplane.cascade_nic).  Grid =
     (hf + 3, n_tiles), pass-major:
 
       pass 0        host_tx — the N sub-flows of a flow share the NIC, so
-                    rates pre-reduce over N and the one-hot matmul runs at
-                    [block_n, L] instead of [N*block_n, L]
-      pass 1..hf    fabric hop p-1, per sub-flow (flat, as before)
+                    rates pre-reduce over N and one one-hot serves the tile
+      pass 1..hf    fabric hop p-1, one one-hot per sub-flow row
       pass hf+1     host_rx — pre-reduced again
       pass hf+2     apply the rx scale -> thr, fuse queue + RED mark
 
-    Each pass first advances the running [N, block_n] rate scratch by the
-    PREVIOUS pass's scale (row-wise via tx for pass 1, per-sub-flow via the
-    fabric one-hot for passes 2..hf+1, row-wise via rx for the final pass).
-    scales_ref row p holds pass p's link load until the last tile converts
-    it in place to the capacity scale."""
+    Each pass first advances the running [N, block_n] rate scratch
+    ``r_ref[t]`` by the PREVIOUS pass's scale (gathered through tx for
+    pass 1, per sub-flow through the fabric one-hot for passes 2..hf+1,
+    through rx for the final pass).  ``scales_ref[p]`` holds pass p's link
+    load until the last tile converts it in place to the capacity scale."""
     p = pl.program_id(0)
     t = pl.program_id(1)
     n_tiles = pl.num_programs(1)
+    n_sub = rate_ref.shape[0]
+    onehot = functools.partial(_onehot, n_links_padded=n_links_padded)
 
-    lids = fab_ref[...]  # [N, block_n, hf] i32 (sentinel = dummy column)
-    N, bn, _ = lids.shape
-    flat_lids = lids.reshape(N * bn, hf)
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (bn, n_links_padded), 1)
-    iota_nb = jax.lax.broadcasted_iota(jnp.int32, (N * bn, n_links_padded), 1)
-    hop_iota = jax.lax.broadcasted_iota(jnp.int32, (N * bn, hf), 1)
-    oh_tx = (iota_b == tx_ref[...][:, None]).astype(jnp.float32)
-    oh_rx = (iota_b == rx_ref[...][:, None]).astype(jnp.float32)
+    def per_sub(fn, lids):
+        """Apply ``fn(j, lid_row)`` to every sub-flow row of a [N, bn] hop."""
+        return [fn(j, lids[j:j + 1]) for j in range(n_sub)]
 
     @pl.when((p == 0) & (t == 0))
     def _init():
         arrival_ref[...] = jnp.zeros_like(arrival_ref)
 
-    stored = pl.load(r_ref, (pl.dslice(t, 1), slice(None), slice(None)))[0]
-
     # ---- advance the running rates by the previous pass's scale ----
     @pl.when(p == 0)
     def _r_fresh():
-        pl.store(r_ref, (pl.dslice(t, 1), slice(None), slice(None)),
-                 rate_ref[...][None])
+        r_ref[t] = rate_ref[...]
 
     @pl.when(p == 1)
     def _r_tx():
-        s = oh_tx @ pl.load(scales_ref, (pl.dslice(0, 1), slice(None)))[0]
-        pl.store(r_ref, (pl.dslice(t, 1), slice(None), slice(None)),
-                 (stored * s[None, :])[None])
+        r_ref[t] = r_ref[t] * _gather(scales_ref[0], onehot(tx_ref[...]))
 
     @pl.when((p >= 2) & (p <= hf + 1))
     def _r_fab():
-        hprev = jnp.clip(p - 2, 0, hf - 1)
-        lid_prev = jnp.sum(jnp.where(hop_iota == hprev, flat_lids, 0), axis=1)
-        oh = (iota_nb == lid_prev[:, None]).astype(jnp.float32)
-        s = oh @ pl.load(scales_ref, (pl.dslice(p - 1, 1), slice(None)))[0]
-        pl.store(r_ref, (pl.dslice(t, 1), slice(None), slice(None)),
-                 (stored * s.reshape(N, bn))[None])
+        srow = scales_ref[p - 1]
+        stored = r_ref[t]
 
-    r = pl.load(r_ref, (pl.dslice(t, 1), slice(None), slice(None)))[0]
+        def step(j, lid_row):
+            r_ref[t, j:j + 1, :] = stored[j:j + 1] * _gather(srow, onehot(lid_row))
+
+        per_sub(step, fab_ref[p - 2])
 
     # ---- accumulate this pass's link load into scales_ref[p] ----
-    def _acc(contrib):
-        acc = pl.load(scales_ref, (pl.dslice(p, 1), slice(None)))[0]
-        acc = jnp.where(t == 0, 0.0, acc)
-        pl.store(scales_ref, (pl.dslice(p, 1), slice(None)), (acc + contrib)[None])
+    def _acc(load_row):
+        scales_ref[p] = jnp.where(t == 0, 0.0, scales_ref[p]) + load_row
 
     @pl.when(p == 0)
     def _load_tx():
-        _acc(jnp.sum(r, axis=0) @ oh_tx)
+        _acc(_scatter(jnp.sum(r_ref[t], axis=0, keepdims=True),
+                      onehot(tx_ref[...])))
 
     @pl.when((p >= 1) & (p <= hf))
     def _load_fab():
-        lid_h = jnp.sum(jnp.where(hop_iota == p - 1, flat_lids, 0), axis=1)
-        oh = (iota_nb == lid_h[:, None]).astype(jnp.float32)
-        _acc(r.reshape(N * bn) @ oh)
+        r = r_ref[t]
+        loads = per_sub(lambda j, lid_row: _scatter(r[j:j + 1], onehot(lid_row)),
+                        fab_ref[p - 1])
+        _acc(functools.reduce(jnp.add, loads))
 
     @pl.when(p == hf + 1)
     def _load_rx():
-        _acc(jnp.sum(r, axis=0) @ oh_rx)
+        _acc(_scatter(jnp.sum(r_ref[t], axis=0, keepdims=True),
+                      onehot(rx_ref[...])))
 
     @pl.when((p <= hf + 1) & (t == n_tiles - 1))
     def _finalize_hop():
-        load = pl.load(scales_ref, (pl.dslice(p, 1), slice(None)))[0]
+        load = scales_ref[p]
         arrival_ref[...] += load
-        scale = jnp.minimum(1.0, cap_ref[...] / jnp.maximum(load, 1.0))
-        pl.store(scales_ref, (pl.dslice(p, 1), slice(None)), scale[None])
+        scales_ref[p] = jnp.minimum(1.0, cap_ref[...] / jnp.maximum(load, 1.0))
 
     @pl.when(p == hf + 2)
     def _write_thr():
-        s = oh_rx @ pl.load(scales_ref, (pl.dslice(hf + 1, 1), slice(None)))[0]
-        thr_ref[...] = r * s[None, :]
+        thr_ref[...] = r_ref[t] * _gather(scales_ref[hf + 1], onehot(rx_ref[...]))
 
     @pl.when((p == hf + 2) & (t == n_tiles - 1))
     def _finalize():
         arr = arrival_ref[...]
         newq = jnp.clip(queue_ref[...] + (arr - cap_ref[...]) * dt / 8.0, 0.0, qmax)
         newq = newq * qmask_ref[...]
-        ramp = (newq - kmin) / (kmax - kmin)
-        mark = jnp.where(newq < kmin, 0.0, jnp.where(newq > kmax, 1.0, ramp * pmax))
         newq_ref[...] = newq
-        mark_ref[...] = mark
+        mark_ref[...] = _red_mark(newq, kmin, kmax, pmax)
 
 
 @functools.partial(
@@ -287,8 +240,8 @@ def _cascade_tiered_kernel(
 )
 def linkload_cascade_tiered(
     fab_links: jax.Array,  # i32[n, N, hf]  (-1 = no hop)
-    tx_link: jax.Array,  # i32[n]
-    rx_link: jax.Array,  # i32[n]
+    tx_link: jax.Array,  # i32[n]  (-1 = no hop)
+    rx_link: jax.Array,  # i32[n]  (-1 = no hop)
     rates: jax.Array,  # f32[n, N]
     queue: jax.Array,  # f32[n_links]
     capacity: jax.Array,  # f32[n_links]
@@ -300,67 +253,62 @@ def linkload_cascade_tiered(
     pmax: float = 0.2,
     dt: float = 10e-6,
     qmax_bytes: float = 8e6,
-    block_n: int = 512,
+    block_n: int = 256,
     interpret: bool = False,
 ):
     """NIC-tiered fused dataplane step: (arrival, new_queue, mark, thr[n, N]).
-    Oracle: kernels/ref.py::linkload_cascade_tiered_ref."""
+    Oracle: kernels/ref.py::linkload_cascade_tiered_ref.  ``block_n`` must
+    be a multiple of 128 when compiled for the TPU."""
     n, n_sub, hf = fab_links.shape
-    dummy = n_links
-    fab = jnp.where(fab_links >= 0, fab_links, dummy).astype(jnp.int32)
+    dummy = n_links  # absent hops land on the first padded column (cap 1e30)
     pad_n = (-n) % block_n
-    if pad_n:
-        fab = jnp.pad(fab, ((0, pad_n), (0, 0), (0, 0)), constant_values=dummy)
-        tx_link = jnp.pad(tx_link, (0, pad_n), constant_values=dummy)
-        rx_link = jnp.pad(rx_link, (0, pad_n), constant_values=dummy)
-        rates = jnp.pad(rates, ((0, pad_n), (0, 0)))
-    # sub-major layout: the scratch keeps block_n on the lane axis
-    fab_t = jnp.swapaxes(fab, 0, 1)  # [N, n_pad, hf]
-    rates_t = jnp.swapaxes(rates, 0, 1)  # [N, n_pad]
-    L_pad = ((n_links + 1 + 127) // 128) * 128
-    queue_p = jnp.pad(queue, (0, L_pad - n_links))
-    cap_p = jnp.pad(capacity[:n_links], (0, L_pad - n_links), constant_values=1e30)
-    qmask_p = jnp.pad(queue_mask[:n_links], (0, L_pad - n_links))
 
+    def flows(x):  # i32[n, ...] -> sentinel-mapped, padded to n + pad_n
+        x = jnp.where(x >= 0, x, dummy).astype(jnp.int32)
+        return jnp.pad(x, ((0, pad_n),) + ((0, 0),) * (x.ndim - 1),
+                       constant_values=dummy)
+
+    # flow axis last (lanes) everywhere: fab [hf, N, n_pad], rows [1, n_pad]
+    fab_t = jnp.transpose(flows(fab_links), (2, 1, 0))
+    tx_r = flows(tx_link)[None, :]
+    rx_r = flows(rx_link)[None, :]
+    rates_t = jnp.pad(rates.astype(jnp.float32), ((0, pad_n), (0, 0))).T
+    L_pad = _pad_links(n_links)
     n_tiles = (n + pad_n) // block_n
-    grid = (hf + 3, n_tiles)
-    arrival, newq, mark, scales, thr = pl.pallas_call(
+    last = hf + 2
+    row = pl.BlockSpec((1, L_pad), lambda p, t: (0, 0))
+    flow_row = pl.BlockSpec((1, block_n), lambda p, t: (0, t))
+    arrival, newq, mark, thr = pl.pallas_call(
         functools.partial(
             _cascade_tiered_kernel,
-            n_links_padded=L_pad, n_sub=n_sub, hf=hf, kmin=kmin, kmax=kmax,
+            n_links_padded=L_pad, hf=hf, kmin=kmin, kmax=kmax,
             pmax=pmax, dt=dt, qmax=qmax_bytes,
         ),
-        grid=grid,
+        grid=(hf + 3, n_tiles),
         in_specs=[
-            pl.BlockSpec((n_sub, block_n, hf), lambda p, t: (0, t, 0)),
-            pl.BlockSpec((block_n,), lambda p, t: (t,)),
-            pl.BlockSpec((block_n,), lambda p, t: (t,)),
+            pl.BlockSpec((hf, n_sub, block_n), lambda p, t: (0, 0, t)),
+            flow_row, flow_row,
             pl.BlockSpec((n_sub, block_n), lambda p, t: (0, t)),
-            pl.BlockSpec((L_pad,), lambda p, t: (0,)),
-            pl.BlockSpec((L_pad,), lambda p, t: (0,)),
-            pl.BlockSpec((L_pad,), lambda p, t: (0,)),
+            row, row, row,
         ],
         out_specs=[
-            pl.BlockSpec((L_pad,), lambda p, t: (0,)),
-            pl.BlockSpec((L_pad,), lambda p, t: (0,)),
-            pl.BlockSpec((L_pad,), lambda p, t: (0,)),
-            pl.BlockSpec((hf + 2, L_pad), lambda p, t: (0, 0)),
-            pl.BlockSpec((n_sub, block_n), lambda p, t: (0, t)),
+            row, row, row,
+            # thr is written in the last pass only: park on block 0 before
+            # it so no unwritten block is ever copied back to HBM
+            pl.BlockSpec((n_sub, block_n),
+                         lambda p, t: (0, jnp.where(p == last, t, 0))),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((L_pad,), jnp.float32),
-            jax.ShapeDtypeStruct((L_pad,), jnp.float32),
-            jax.ShapeDtypeStruct((L_pad,), jnp.float32),
-            jax.ShapeDtypeStruct((hf + 2, L_pad), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct((1, L_pad), jnp.float32)] * 3 + [
             jax.ShapeDtypeStruct((n_sub, n + pad_n), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((n_tiles, n_sub, block_n), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((hf + 2, 1, L_pad), jnp.float32),  # per-pass load/scale
+            pltpu.VMEM((n_tiles, n_sub, block_n), jnp.float32),  # running rates
+        ],
         interpret=interpret,
-    )(fab_t, tx_link, rx_link, rates_t, queue_p, cap_p, qmask_p)
-    return (
-        arrival[:n_links], newq[:n_links], mark[:n_links],
-        jnp.swapaxes(thr, 0, 1)[:n],
-    )
+    )(fab_t, tx_r, rx_r, rates_t, _row(queue, L_pad),
+      _row(capacity[:n_links], L_pad, 1e30), _row(queue_mask[:n_links], L_pad))
+    return arrival[0, :n_links], newq[0, :n_links], mark[0, :n_links], thr.T[:n]
 
 
 @functools.partial(
@@ -382,55 +330,20 @@ def linkload_cascade(
     pmax: float = 0.2,
     dt: float = 10e-6,
     qmax_bytes: float = 8e6,
-    block_n: int = 512,
+    block_n: int = 256,
     interpret: bool = False,
 ):
-    """Fused dataplane step: (arrival, new_queue, mark, thr) — the whole
-    offered-load -> queue -> RED/ECN pipeline of the fluid simulator in one
-    kernel call.  Oracle: kernels/ref.py::linkload_cascade_ref."""
-    n, hops = link_ids.shape
-    dummy = n_links  # -1 hops land on the first padded column
-    lid = jnp.where(link_ids >= 0, link_ids, dummy).astype(jnp.int32)
-    pad_n = (-n) % block_n
-    if pad_n:
-        lid = jnp.pad(lid, ((0, pad_n), (0, 0)), constant_values=dummy)
-        rates = jnp.pad(rates, (0, pad_n))
-    L_pad = ((n_links + 1 + 127) // 128) * 128
-    queue_p = jnp.pad(queue, (0, L_pad - n_links))
-    cap_p = jnp.pad(capacity[:n_links], (0, L_pad - n_links), constant_values=1e30)
-    qmask_p = jnp.pad(queue_mask[:n_links], (0, L_pad - n_links))
-
-    n_tiles = (n + pad_n) // block_n
-    grid = (hops + 1, n_tiles)
-    arrival, newq, mark, scales, thr = pl.pallas_call(
-        functools.partial(
-            _cascade_kernel,
-            n_links_padded=L_pad, hops=hops, kmin=kmin, kmax=kmax, pmax=pmax,
-            dt=dt, qmax=qmax_bytes,
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, hops), lambda h, t: (t, 0)),
-            pl.BlockSpec((block_n,), lambda h, t: (t,)),
-            pl.BlockSpec((L_pad,), lambda h, t: (0,)),
-            pl.BlockSpec((L_pad,), lambda h, t: (0,)),
-            pl.BlockSpec((L_pad,), lambda h, t: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((L_pad,), lambda h, t: (0,)),
-            pl.BlockSpec((L_pad,), lambda h, t: (0,)),
-            pl.BlockSpec((L_pad,), lambda h, t: (0,)),
-            pl.BlockSpec((hops, L_pad), lambda h, t: (0, 0)),
-            pl.BlockSpec((block_n,), lambda h, t: (t,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((L_pad,), jnp.float32),
-            jax.ShapeDtypeStruct((L_pad,), jnp.float32),
-            jax.ShapeDtypeStruct((L_pad,), jnp.float32),
-            jax.ShapeDtypeStruct((hops, L_pad), jnp.float32),
-            jax.ShapeDtypeStruct((n + pad_n,), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((n_tiles, block_n), jnp.float32)],
-        interpret=interpret,
-    )(lid, rates, queue_p, cap_p, qmask_p)
-    return arrival[:n_links], newq[:n_links], mark[:n_links], thr[:n]
+    """Flat fused dataplane step: (arrival, new_queue, mark, thr) — every hop
+    per flow.  The same kernel as ``linkload_cascade_tiered`` with one
+    sub-flow per flow: hop 0 is its tx pass, the last hop its rx pass and
+    the hops between its fabric passes (one absent hop when there are
+    none).  Oracle: kernels/ref.py::linkload_cascade_ref."""
+    assert link_ids.shape[1] >= 2, "a flat route has a tx and an rx hop"
+    lid = link_ids.astype(jnp.int32)
+    fab = lid[:, 1:-1] if lid.shape[1] > 2 else jnp.full((lid.shape[0], 1), -1, jnp.int32)
+    arrival, newq, mark, thr = linkload_cascade_tiered(
+        fab[:, None, :], lid[:, 0], lid[:, -1], rates[:, None], queue,
+        capacity, queue_mask, n_links=n_links, kmin=kmin, kmax=kmax, pmax=pmax,
+        dt=dt, qmax_bytes=qmax_bytes, block_n=block_n, interpret=interpret,
+    )
+    return arrival, newq, mark, thr[:, 0]

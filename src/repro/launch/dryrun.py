@@ -1,5 +1,11 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+
+if __name__ == "__main__":
+    # the CLI compiles on 512 virtual CPU devices; importing this module
+    # (collective_bytes) leaves the caller's backend and flags alone
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+        os.environ.get("XLA_FLAGS"), "--xla_force_host_platform_device_count=512")))
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 

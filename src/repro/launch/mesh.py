@@ -8,8 +8,6 @@ from __future__ import annotations
 
 import jax
 
-from repro.dist import _compat  # noqa: F401  (jax API shims for 0.4.x)
-
 
 def _make(shape, axes):
     axis_types = (jax.sharding.AxisType.Auto,) * len(axes)

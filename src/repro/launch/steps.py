@@ -27,16 +27,6 @@ def make_train_step(cfg, opt_cfg: opt_mod.AdamWConfig, mesh=None, grad_sync: str
     has_pod = mesh is not None and "pod" in mesh.axis_names and mesh.shape["pod"] > 1
 
     if grad_sync == "seqbalance" and has_pod:
-        auto_axes = set(mesh.axis_names) - {"pod"}
-        if auto_axes and getattr(jax.shard_map, "is_legacy_shim", False):
-            # jax 0.4.x's experimental `auto=` partial-manual lowering
-            # aborts the process inside the SPMD partitioner for this
-            # program shape — fail at build time with a real signal instead
-            raise NotImplementedError(
-                "seqbalance grad sync over a multi-axis mesh (manual pod + "
-                f"auto {sorted(auto_axes)}) needs jax>=0.5's native "
-                "jax.shard_map; use a 1-D pod mesh (launch.mesh."
-                "make_pod_mesh) or grad_sync='xla' on this toolchain")
         def train_step(state, batch):
             def per_pod(params, batch_shard):
                 def lf(p):

@@ -30,13 +30,16 @@ Backends (both layouts)
     matmuls on the MXU, the cascade walks hops in the grid, and queue/mark
     fuse into the final grid step.
   * ``pallas_interpret`` — the same kernel interpreted on CPU (tests).
-  * ``auto``   — pallas on TPU, xla everywhere else.
+  * ``auto``   — pallas (compiled) on TPU, xla everywhere else; the
+    interpreter is never picked implicitly.
 
 DRILL's per-packet spray does not fit the per-path cascade (it splits one
 sub-flow over ALL paths by queue-depth weights), so its 2-tier dataplane
 lives here too (``drill_spray``) and is shared by both engines.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -416,6 +419,8 @@ def drill_spray(
     (tiny) leaf axis — [n, L] gemms beat XLA:CPU's serial scatter-add on
     the [n, P] operands by ~2x at DRILL's collapsed-window sizes, and the
     one-hot gather back is exact (one 1.0 term, L-1 exact +0.0 terms).
+    The matmuls run at ``Precision.HIGHEST``: on the TPU an f32 ``@``
+    defaults to bf16 passes, which would round the bps rates.
 
     Returns (arrival[n_links+1], thr[n] delivered rate before the go-back-N
     penalty, w[n, P] path weights, pq[n, P] per-path queue bytes).
@@ -427,6 +432,7 @@ def drill_spray(
     L_, S_ = topo.n_leaf, topo.n_paths
     h0 = nl - 2 * topo.n_hosts
     up0 = 0
+    dot = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
     pq = path_queue_2tier(topo, queue, src_leaf, dst_leaf)  # [n, P]
     w = baselines.drill_weights(pq, drill_q0) * active0
     oh_s = (src_leaf[:, None] == jnp.arange(L_)[None, :]).astype(jnp.float32)
@@ -439,17 +445,17 @@ def drill_spray(
     r0 = rc0 * s_tx  # [n]
     # hop 1: uplinks (per-path split)
     r0w = r0[:, None] * w  # [n, P]
-    up_load = oh_s.T @ r0w  # [L, P]
+    up_load = dot(oh_s.T, r0w)  # [L, P]
     arrival = arrival.at[up0 : up0 + L_ * S_].add(up_load.reshape(-1))
     cap_up = cap[up0 : up0 + L_ * S_].reshape(L_, S_)
     s_up = jnp.minimum(1.0, cap_up / jnp.maximum(up_load, 1.0))
-    r1 = r0w * (oh_s @ s_up)  # [n, P]
+    r1 = r0w * dot(oh_s, s_up)  # [n, P]
     # hop 2: downlinks
-    dn_load = oh_d.T @ r1  # [L, P] (by dst)
+    dn_load = dot(oh_d.T, r1)  # [L, P] (by dst)
     arrival = arrival.at[L_ * S_ : 2 * L_ * S_].add(dn_load.T.reshape(-1))
     cap_dn = cap[L_ * S_ : 2 * L_ * S_].reshape(S_, L_)
     s_dn = jnp.minimum(1.0, cap_dn.T / jnp.maximum(dn_load, 1.0))  # [L, P]
-    r2 = r1 * (oh_d @ s_dn)  # [n, P]
+    r2 = r1 * dot(oh_d, s_dn)  # [n, P]
     # hop 3: receiver NIC
     r2sum = jnp.sum(r2, -1)
     rx_load = jax.ops.segment_sum(r2sum, dst, num_segments=topo.n_hosts)

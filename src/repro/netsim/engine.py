@@ -31,6 +31,7 @@ import numpy as np
 
 from repro.core import baselines, congestion_table as ctab, hashing, routing, shaper
 from repro.netsim import dataplane, dcqcn as dcqcn_mod
+from repro.netsim.compile_cache import enable_compile_cache
 from repro.netsim.topology import Topology
 from repro.netsim.workloads import Trace
 
@@ -407,6 +408,7 @@ def simulate(
     ``reorder`` (float packets or None) enables the flowcell reordering
     cost as a TRACED budget: one compiled program per (topo, cfg) covers
     every budget value.  ``None`` dispatches the pre-flowcell program."""
+    enable_compile_cache()
     arrays = (trace.sizes, trace.arrivals, trace.src, trace.dst,
               trace.flow_id, trace.valid, trace.spray)
     arrays = tuple(jnp.asarray(a) for a in arrays)

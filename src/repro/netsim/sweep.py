@@ -22,8 +22,9 @@ per sim.  This runner instead:
     device count and dispatches it as ONE pmap-of-vmap (one shard of the
     batch per device); the single-device path is untouched and stays
     bit-identical;
-  * points JAX's persistent compilation cache at a scratch dir
-    (``enable_compile_cache``): sweeps relaunch the same programs every
+  * turns on JAX's persistent compilation cache
+    (``compile_cache.enable_compile_cache``: ``JAX_COMPILATION_CACHE_DIR``
+    if set, else ``<checkout>/.jax_cache``): sweeps relaunch the same programs every
     process, so from the second process on the several-seconds-per-program
     XLA compiles are disk hits.
 
@@ -37,7 +38,6 @@ import dataclasses
 import functools
 import hashlib
 import os
-import threading
 import time
 
 import jax
@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.netsim import compact
+from repro.netsim.compile_cache import enable_compile_cache
 from repro.netsim.engine import SimConfig, StepOutputs, line_rate_of
 from repro.netsim.topology import Topology
 from repro.netsim.workloads import Trace
@@ -56,8 +57,6 @@ _JIT_CACHE: dict = {}
 _CACHE_STATS = {"builds": 0, "hits": 0}
 _OBS_STATS = {"spill_retries": 0, "job_retries": 0, "job_timeouts": 0,
               "job_failures": 0}
-_COMPILE_CACHE_SET = False
-_COMPILE_CACHE_LOCK = threading.Lock()
 _JAX_TRACE_DIR: str | None = None
 
 
@@ -83,57 +82,6 @@ def obs_stats() -> dict:
     return out
 
 
-def enable_compile_cache() -> str | None:
-    """Point JAX's persistent compilation cache at REPRO_COMPILE_CACHE
-    (default: a per-user dir under $TMPDIR).  Paper sweeps re-launch the
-    same (scheme, topology, shape) programs in every process — several
-    seconds of XLA compile each — so the second process onward starts
-    warm.  Set REPRO_COMPILE_CACHE=0 to disable.  Returns the dir in use
-    (None when disabled).  Idempotent; called lazily by run_batch."""
-    global _COMPILE_CACHE_SET
-    try:  # never clobber a cache dir the user configured themselves
-        configured = jax.config.jax_compilation_cache_dir
-    except AttributeError:
-        return None
-    if configured:
-        return configured
-    path = os.environ.get("REPRO_COMPILE_CACHE")
-    if path is None:
-        import tempfile
-
-        uid = os.getuid() if hasattr(os, "getuid") else "user"
-        path = os.path.join(tempfile.gettempdir(), f"repro-xla-cache-{uid}")
-    if path in ("", "0"):
-        return None
-    with _COMPILE_CACHE_LOCK:  # run_jobs calls this from worker threads
-        if not _COMPILE_CACHE_SET:
-            try:
-                jax.config.update("jax_compilation_cache_dir", path)
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 0.5)
-                jax.config.update(
-                    "jax_persistent_cache_min_entry_size_bytes", 0)
-                # the cache module latches "no dir configured" on the first
-                # compile of the process (e.g. a jnp op at import time) and
-                # never re-reads the config — reset so the dir takes effect
-                from jax._src import compilation_cache as _cc
-
-                _cc.reset_cache()
-            except (AttributeError, ImportError, TypeError, ValueError) as e:
-                # older jax spellings only — anything else should surface.
-                # degrading silently costs minutes of recompiles per process,
-                # so say it once out loud.
-                import warnings
-
-                warnings.warn(
-                    f"persistent XLA compile cache unavailable ({e!r}); "
-                    "sweep processes will recompile from scratch",
-                    RuntimeWarning, stacklevel=2)
-                return None
-            _COMPILE_CACHE_SET = True
-    return path
-
-
 def clear_cache() -> None:
     """Drop compiled executables (benchmarks call this to time cold runs)."""
     _JIT_CACHE.clear()
@@ -152,15 +100,7 @@ def _maybe_start_jax_trace() -> None:
     path = os.environ.get("REPRO_JAX_TRACE_DIR")
     if not path or _JAX_TRACE_DIR is not None:
         return
-    try:
-        jax.profiler.start_trace(path)
-    except Exception as e:  # pragma: no cover - backend-dependent
-        import warnings
-
-        warnings.warn(f"REPRO_JAX_TRACE_DIR set but start_trace failed "
-                      f"({e!r})", RuntimeWarning, stacklevel=2)
-        _JAX_TRACE_DIR = ""
-        return
+    jax.profiler.start_trace(path)
     _JAX_TRACE_DIR = path
     import atexit
 
@@ -390,12 +330,7 @@ def _trace_span(name: str = "repro.sweep.dispatch"):
     trace is being captured (REPRO_JAX_TRACE_DIR -> ``start_trace``), the
     sweep executions show up as named spans in perfetto/tensorboard.
     Near-free when no trace is active."""
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # pragma: no cover - older jax spellings
-        import contextlib
-
-        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
 
 
 def _dispatch(topo, cfg, W, F_pad, A, n_steps, stacked, B, capacity=None,

@@ -53,18 +53,13 @@ def runmeta() -> dict:
     """Provenance stamp: run id, git sha, host, jax device count/backend,
     UTC wall clock.  Cheap after the first call (sha is cached; jax is
     already initialized by any caller that simulates)."""
-    try:
-        import jax
+    import jax
 
-        n_devices = jax.local_device_count()
-        backend = jax.default_backend()
-    except Exception:  # pragma: no cover - jax always present in this repo
-        n_devices, backend = 0, "unknown"
     return dict(
         run_id=_RUN_ID,
         git_sha=_git_sha(),
         host=socket.gethostname(),
-        n_devices=int(n_devices),
-        backend=backend,
+        n_devices=jax.local_device_count(),
+        backend=jax.default_backend(),
         time_utc=time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
     )

@@ -20,7 +20,7 @@ import pytest
 
 pytest.importorskip("hypothesis", reason="property tests need hypothesis")
 
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import example, given, settings, strategies as st  # noqa: E402
 
 from repro.kernels import linkload as ll, ref  # noqa: E402
 from repro.netsim import compact, dataplane, engine, topology, workloads  # noqa: E402
@@ -81,6 +81,7 @@ def test_tiered_cascade_equals_flat(seed, n, n_sub, hf, L):
     hf=st.integers(1, 4),
     L=st.integers(3, 50),
 )
+@example(seed=0, n=1, n_sub=1, hf=1, L=3)  # smallest instance: one flow, one hop
 def test_tiered_kernel_interpret_equals_ref(seed, n, n_sub, hf, L):
     fab, tx, rx, rates, queue, cap, qmask = _random_instance(seed, n, n_sub, hf, L)
     a1, q1, m1, t1 = ll.linkload_cascade_tiered(
@@ -103,6 +104,7 @@ def test_tiered_kernel_interpret_equals_ref(seed, n, n_sub, hf, L):
     load=st.sampled_from([0.4, 0.7]),
     scheme=st.sampled_from(engine.SCHEMES),
 )
+@example(seed=0, load=0.7, scheme="drill")  # the known DRILL divergence
 def test_cached_route_step_equals_recompute(seed, load, scheme):
     """The compact engine snapshots routes/link-ids at admission; the dense
     oracle re-derives them from the topology every step.  Random traces
