@@ -465,10 +465,10 @@ def bench_adaptive_dt(fast=True):
 
 
 # ------------------------------------------- --profile (run.py flag)
-def bench_profile_phases(fast=True, schemes=("seqbalance", "ecmp")):
-    """Per-phase step-cost breakdown of the compact engine (admit /
-    cascade / dcqcn / finish) on the fig12 fast setup, so perf PRs can
-    attribute wins.  Not part of ALL — enabled by ``run.py --profile``."""
+def bench_quiescence_profile(fast=True):
+    """Quiescence occupancy of the compact engine (DESIGN.md §15).  Not
+    part of ALL — enabled by ``run.py --profile``.  Per-phase step time is
+    read from a profiler trace on the chip (``bench/``), not timed here."""
     from repro.netsim import profile, topology
     from repro.netsim.engine import SimConfig
 
@@ -476,21 +476,6 @@ def bench_profile_phases(fast=True, schemes=("seqbalance", "ecmp")):
     arr = 2.5e-3 if fast else 10e-3
     trace = _poisson(topo, "alistorage", 0.8, arr)
     record = {}
-    for scheme in schemes:
-        cfg = SimConfig(scheme=scheme, duration_s=arr * 4)
-        times = profile.profile_phases(topo, cfg, trace)
-        # TimeUs phases carry the full sample distribution: store
-        # {min_us, mean_us, std_us, iters} per phase (flight-log schema),
-        # plain floats/ints (phase_sum, window_slots) stay scalar
-        record[scheme] = {
-            k: v.stats() if isinstance(v, profile.TimeUs)
-            else (round(v, 2) if isinstance(v, float) else v)
-            for k, v in times.items()}
-        for phase in ("admit", "cascade", "dcqcn", "finish"):
-            emit(f"profile_{scheme}_{phase}", times[phase],
-                 f"{times[phase]/max(times['phase_sum'],1e-9)*100:.0f}%_of_phase_sum")
-        emit(f"profile_{scheme}_step_fused", times["step_fused"],
-             f"phase_sum_{times['phase_sum']:.1f}us_W_{times['window_slots']}")
 
     # quiescence occupancy (DESIGN.md §15): replay the fixed-dt oracle and
     # record which chunk boundaries the adaptive engine would fast-forward

@@ -24,8 +24,8 @@ def main() -> None:
     ap.add_argument("--json", default="BENCH_netsim.json",
                     help="output path for the machine-readable record")
     ap.add_argument("--profile", action="store_true",
-                    help="per-phase compact-step timing rows (admit / "
-                         "cascade / dcqcn / finish) for perf attribution")
+                    help="quiescence-occupancy rows of the compact engine "
+                         "(adaptive-dt fast-forward coverage)")
     args = ap.parse_args()
 
     from benchmarks import common, paper_benches
@@ -41,7 +41,7 @@ def main() -> None:
                                          bench_telemetry, bench_obs,
                                          bench_flowcell]
     if args.profile:
-        benches.append(paper_benches.bench_profile_phases)
+        benches.append(paper_benches.bench_quiescence_profile)
     print("name,us_per_call,derived")
     t0 = time.time()
     failed = []

@@ -136,6 +136,7 @@ def linkload(
         out_specs=[row, row, row],
         out_shape=[jax.ShapeDtypeStruct((1, L_pad), jnp.float32)] * 3,
         interpret=interpret,
+        name="linkload",
     )(lid_t, rates_r, _row(queue, L_pad), _row(capacity[:n_links], L_pad, 1e30))
     return load[0, :n_links], newq[0, :n_links], mark[0, :n_links]
 
@@ -306,6 +307,7 @@ def linkload_cascade_tiered(
             pltpu.VMEM((n_tiles, n_sub, block_n), jnp.float32),  # running rates
         ],
         interpret=interpret,
+        name="linkload_cascade_tiered",
     )(fab_t, tx_r, rx_r, rates_t, _row(queue, L_pad),
       _row(capacity[:n_links], L_pad, 1e30), _row(queue_mask[:n_links], L_pad))
     return arrival[0, :n_links], newq[0, :n_links], mark[0, :n_links], thr.T[:n]

@@ -51,6 +51,7 @@ from repro.netsim.engine import (
 )
 from repro.netsim.topology import Topology
 from repro.netsim.workloads import Trace
+from repro.obs.scopes import scope
 
 
 class SlotCache(NamedTuple):
@@ -148,8 +149,9 @@ def plan_single_window(topo: Topology, cfg: SimConfig, arrays: tuple,
                        F_pad: int) -> tuple[int, int]:
     """(W, A) for a single sorted trace: the concurrency-bound window
     (128-bucketed, floored at min(128, F_pad)) and the exact-peak admission
-    lane (32-bucketed).  Shared by ``simulate_compact`` and the --profile
-    harness so profiling always times the production shapes."""
+    lane (32-bucketed).  Shared by ``simulate_compact`` and
+    ``profile.quiescence_profile``, so the replay runs the production
+    shapes."""
     line_rate = float(np.asarray(line_rate_of(topo)))
     bound = max_concurrency_bound(arrays[0], arrays[1], arrays[5], line_rate)
     W = int(min(((bound + 127) // 128) * 128, F_pad))
@@ -249,9 +251,11 @@ def build_compact_sim(topo: Topology, cfg: SimConfig, trace_arrays, W: int, F_pa
     flow's parent chunk straddles more than one path.  ``None`` (Python
     gate, same convention as ``loss``) traces the exact pre-flowcell
     program — the degenerate pin AND the "cost-free reordering" bench arm.
-    Returns (init_state, step_fn, phases) — ``phases`` maps the profile
-    phase names (admit / cascade / dcqcn / finish) to the closures
-    ``step_fn`` composes, for benchmarks/run.py --profile."""
+    Returns (init_state, step_fn, phases) — ``phases`` maps each phase's
+    name (admit / cascade / dcqcn / finish, and the adaptive-dt quiesce /
+    fast_forward) to its closure.  Each closure traces under the
+    ``jax.named_scope`` of its name (``obs.scopes``), so the compiled
+    program's instructions carry the phase they came from."""
     arrs = tuple(jnp.asarray(a) for a in trace_arrays)
     if len(arrs) == 6:  # legacy 6-tuple: no flowcell splitting anywhere
         arrs = arrs + (jnp.ones_like(arrs[2]),)
@@ -397,6 +401,7 @@ def build_compact_sim(topo: Topology, cfg: SimConfig, trace_arrays, W: int, F_pa
             cache=cache,
         )
 
+    @scope("admit")
     def admit_phase(state: CompactState):
         """Admission (optionally gated: skipped once every flow has
         admitted) plus the flowlet schemes' per-step reroute.  Step time
@@ -437,6 +442,7 @@ def build_compact_sim(topo: Topology, cfg: SimConfig, trace_arrays, W: int, F_pa
             st = st._replace(path=path)
         return st
 
+    @scope("cascade")
     def cascade_phase(state: CompactState):
         """Offered rates -> NIC-tiered hop cascade -> queue/ECN marks.
         Returns (arrival, new_queue, thr, p_sub, p_sub_fabric, rc, active)."""
@@ -502,6 +508,7 @@ def build_compact_sim(topo: Topology, cfg: SimConfig, trace_arrays, W: int, F_pa
                 )[:, None]
         return arrival, new_queue, thr, p_sub, p_sub_fabric, rc, active
 
+    @scope("dcqcn")
     def dcqcn_phase(state: CompactState, p_sub, active):
         flow_salt = state.cache.salt if cfg.scheme == "seqbalance" \
             else state.cache.salt[:, :1]
@@ -512,6 +519,7 @@ def build_compact_sim(topo: Topology, cfg: SimConfig, trace_arrays, W: int, F_pa
         )
         return cc
 
+    @scope("finish")
     def finish_phase(state: CompactState, t, thr, active, rc, p_sub_fabric):
         """Transfer progress, bitmap CQE, scatter-on-finish, Congestion
         Packet bookkeeping.  Returns (remaining, sub_done, cqe_bitmap,
@@ -569,18 +577,23 @@ def build_compact_sim(topo: Topology, cfg: SimConfig, trace_arrays, W: int, F_pa
             cnp_pkts=state.cnp_pkts + exp_cong_pkts,
             step=state.step + 1,
         )
-        out = StepOutputs(
+        return new_state, step_outputs(arrival, active, thr, exp_cong_pkts,
+                                       new_queue)
+
+    @scope("outputs")
+    def step_outputs(arrival, active, thr, exp_cong_pkts, new_queue):
+        return StepOutputs(
             uplink_load=arrival[jnp.asarray(topo.uplink_ids)],
             goodput_total=jnp.sum(jnp.where(active, thr, 0.0)),
             cnp_rate=exp_cong_pkts,
             max_queue=jnp.max(new_queue[:nl]),
         )
-        return new_state, out
 
     # ---------------- event-driven adaptive dt (DESIGN.md §15) ----------
     uplink_ids = jnp.asarray(topo.uplink_ids)
     s_win = cfg.uplink_sample_every
 
+    @scope("quiesce")
     def quiesce_phase(state: CompactState, span: int):
         """Quiescence predicate for a ``span``-step macro-step starting at
         ``state.step``: True iff every one of those steps is provably
@@ -646,6 +659,7 @@ def build_compact_sim(topo: Topology, cfg: SimConfig, trace_arrays, W: int, F_pa
         return jax.lax.cond(
             p_arr & p_cap, steady_or_idle, lambda st: jnp.bool_(False), state)
 
+    @scope("fast_forward")
     def fast_forward_phase(state: CompactState, span: int):
         """Advance ``span`` steps in closed form — valid exactly when
         ``quiesce_phase(state, span)`` holds.  Queues follow the analytic
@@ -808,6 +822,7 @@ def run_core(topo: Topology, cfg: SimConfig, W: int, F_pad: int, A: int,
         max_queue=jnp.zeros((n_steps,), jnp.float32),
     )
 
+    @scope("chunk")
     def alive(st):
         return (
             (st.admitted < n_valid)
@@ -815,6 +830,7 @@ def run_core(topo: Topology, cfg: SimConfig, W: int, F_pad: int, A: int,
             | (jnp.max(st.queue[:nl]) > 0.0)
         )
 
+    @scope("chunk")
     def splice(outs, o, k0, length):
         """Write a block's per-step output slab into the preallocated
         horizon outputs at the (chunk-aligned, so sample-window-aligned)
@@ -865,6 +881,7 @@ def run_core(topo: Topology, cfg: SimConfig, W: int, F_pad: int, A: int,
                 def cap_row_of(step):
                     return cap_row_r
 
+        @scope("chunk")
         def rec_chunk(ring, st0, st2, o, length, ff):
             """One ring row from a block's raw slab + boundary state.
             ``o.uplink_load`` is per-step for scanned blocks and per-window
@@ -885,18 +902,20 @@ def run_core(topo: Topology, cfg: SimConfig, W: int, F_pad: int, A: int,
         horizon = n_chunks * K
         quiesce, fast_forward = phases["quiesce"], phases["fast_forward"]
 
-        def ff_block(st0, o0):
-            st2, o = fast_forward(st0, macro)
+        @scope("chunk")
+        def ff_splice(o0, o, k0):
             gp = jax.lax.dynamic_update_slice(
-                o0.goodput_total, o.goodput_total, (st0.step,))
-            cn = jax.lax.dynamic_update_slice(o0.cnp_rate, o.cnp_rate,
-                                              (st0.step,))
-            mq = jax.lax.dynamic_update_slice(o0.max_queue, o.max_queue,
-                                              (st0.step,))
+                o0.goodput_total, o.goodput_total, (k0,))
+            cn = jax.lax.dynamic_update_slice(o0.cnp_rate, o.cnp_rate, (k0,))
+            mq = jax.lax.dynamic_update_slice(o0.max_queue, o.max_queue, (k0,))
             up = jax.lax.dynamic_update_slice(
                 o0.uplink_load, o.uplink_load,
-                (st0.step // s,) + (0,) * len(uplink_shape))
-            return st2, StepOutputs(up, gp, cn, mq), o
+                (k0 // s,) + (0,) * len(uplink_shape))
+            return StepOutputs(up, gp, cn, mq)
+
+        def ff_block(st0, o0):
+            st2, o = fast_forward(st0, macro)
+            return st2, ff_splice(o0, o, st0.step), o
 
         if record is None:
             def body(c):

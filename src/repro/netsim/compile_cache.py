@@ -36,6 +36,14 @@ def enable_compile_cache() -> str:
                                   DEFAULT_COMPILE_CACHE)
             jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
             jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+            # key on the HLO metadata too: the step's named scopes live
+            # there (``obs.scopes``), and a cache shared with a build from
+            # before them holds the same program keyed alike, whose
+            # ``op_name``s ``sweep.op_phases`` would then read.  The
+            # metadata holds source lines and call sites, so a program
+            # traced from a new call site or after an edit compiles once
+            # more.
+            jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
             # the cache module latches "no dir configured" on the first
             # compile of the process (e.g. a jnp op at import time) and
             # never re-reads the config — reset so the dir takes effect

@@ -1,25 +1,14 @@
-"""Per-phase timing of the compact-engine step (benchmarks/run.py --profile).
+"""Quiescence occupancy of the compact engine (benchmarks/run.py --profile).
 
-The compact step is composed of four phase closures (netsim/compact.py
-``build_compact_sim`` returns them alongside ``step_fn``):
+``quiescence_profile`` replays a fixed-dt run chunk by chunk and records
+which chunk boundaries the adaptive engine would have fast-forwarded — the
+quiescence occupancy (fraction of the horizon coverable in closed form) and
+the macro-step length histogram — and times the predicate jitted alone.
 
-  admit   — searchsorted admission, slot resets, route-cache fill, routing
-  cascade — offered rates -> NIC-tiered hop cascade -> queue/ECN marks
-  dcqcn   — per-sub-flow rate control update
-  finish  — transfer progress, bitmap CQE, scatter-on-finish, table update
-  quiesce — adaptive-dt quiescence predicate (one chunk-boundary check)
-
-``quiescence_profile`` additionally replays a fixed-dt run chunk by chunk
-and records which chunk boundaries the adaptive engine would have
-fast-forwarded — the quiescence occupancy (fraction of the horizon
-coverable in closed form) and the macro-step length histogram.
-
-Each phase is jitted and timed IN ISOLATION on a mid-simulation state (the
-same state for every phase, reached by scanning ``warm_steps`` real steps),
-so future perf PRs can attribute wins.  Phase times do not add up exactly
-to the fused step (XLA fuses across phase boundaries and the isolated
-phases pay their own dispatch), so the fused per-step time is reported
-alongside as ``step_fused``.
+Where the step's device time goes, phase by phase, is read from a profiler
+trace of the real fused program instead: each phase runs under a named
+scope (``obs.scopes``), and the benchmark sums device time per scope
+(``bench/harness/phases.py``).
 """
 from __future__ import annotations
 
@@ -37,7 +26,7 @@ from repro.netsim.workloads import Trace
 
 class TimeUs(float):
     """A per-call time in µs that is still a float (the value is the MIN
-    over iterations — the least-noise estimator every phase table keys on)
+    over iterations — the least-noise estimator)
     but carries the full per-iteration sample distribution for the flight
     log / bench JSON: ``.min_us`` / ``.mean_us`` / ``.std_us`` /
     ``.samples``, or all four via ``.stats()``."""
@@ -79,52 +68,6 @@ def _time_us(fn, *args, iters: int) -> TimeUs:
         jax.block_until_ready(fn(*args))
         samples.append((time.perf_counter() - t0) * 1e6)
     return TimeUs(samples)
-
-
-def profile_phases(
-    topo: Topology, cfg: SimConfig, trace: Trace, *,
-    warm_steps: int = 200, iters: int = 30,
-) -> dict[str, float]:
-    """Time each compact-step phase on a warmed mid-sim state.  Returns
-    {phase: µs} plus ``step_fused`` (the whole fused step) and
-    ``phase_sum`` (sum of the isolated phases, for fusion-gap context)."""
-    arrays, _, F = compact.sort_trace(trace)
-    F_pad = max(F, 1)
-    W, A = compact.plan_single_window(topo, cfg, arrays, F_pad)
-    jarrays = tuple(jnp.asarray(a) for a in arrays)
-    _, step_fn, phases = compact.build_compact_sim(topo, cfg, jarrays, W, F_pad, A)
-
-    @jax.jit
-    def warm(st):
-        st2, _ = jax.lax.scan(step_fn, st, None, length=warm_steps)
-        return st2
-
-    st = jax.block_until_ready(warm(compact.init_compact_state(topo, cfg, W, F_pad)))
-    t = st.step.astype(jnp.float32) * cfg.dt
-
-    admit = jax.jit(phases["admit"])
-    cascade = jax.jit(phases["cascade"])
-    dcqcn = jax.jit(phases["dcqcn"])
-    finish = jax.jit(phases["finish"])
-    step = jax.jit(step_fn)
-    K, _, _ = compact.plan_chunks(cfg, int(round(cfg.duration_s / cfg.dt)))
-    quiesce = jax.jit(lambda s: phases["quiesce"](s, K))
-
-    st_admit = jax.block_until_ready(admit(st))
-    arrival, new_queue, thr, p_sub, p_fab, rc, active = cascade(st_admit)
-
-    out = {
-        "admit": _time_us(admit, st, iters=iters),
-        "cascade": _time_us(cascade, st_admit, iters=iters),
-        "dcqcn": _time_us(dcqcn, st_admit, p_sub, active, iters=iters),
-        "finish": _time_us(
-            finish, st_admit, t, thr, active, rc, p_fab, iters=iters),
-        "step_fused": _time_us(step, st, iters=iters),
-        "quiesce": _time_us(quiesce, st, iters=iters),
-    }
-    out["phase_sum"] = sum(out[k] for k in ("admit", "cascade", "dcqcn", "finish"))
-    out["window_slots"] = W
-    return out
 
 
 def quiescence_profile(

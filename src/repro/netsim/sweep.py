@@ -49,6 +49,7 @@ from repro.netsim.compile_cache import enable_compile_cache
 from repro.netsim.engine import SimConfig, StepOutputs, line_rate_of
 from repro.netsim.topology import Topology
 from repro.netsim.workloads import Trace
+from repro.obs import scopes
 
 F_BUCKET = 2048
 W_BUCKET = 256
@@ -57,7 +58,9 @@ _JIT_CACHE: dict = {}
 _CACHE_STATS = {"builds": 0, "hits": 0}
 _OBS_STATS = {"spill_retries": 0, "job_retries": 0, "job_timeouts": 0,
               "job_failures": 0}
-_JAX_TRACE_DIR: str | None = None
+# executable-cache key -> the abstract arguments (``jax.ShapeDtypeStruct``
+# pytree) of a single-device executable, stored when it is built
+_ABSTRACT: dict = {}
 
 
 def cache_stats() -> dict:
@@ -85,26 +88,11 @@ def obs_stats() -> dict:
 def clear_cache() -> None:
     """Drop compiled executables (benchmarks call this to time cold runs)."""
     _JIT_CACHE.clear()
+    _ABSTRACT.clear()
     _CACHE_STATS["builds"] = 0
     _CACHE_STATS["hits"] = 0
     for k in _OBS_STATS:
         _OBS_STATS[k] = 0
-
-
-def _maybe_start_jax_trace() -> None:
-    """Latch ``jax.profiler.start_trace`` on REPRO_JAX_TRACE_DIR: set the
-    env var to a directory to capture a device-level profiler trace of the
-    sweep dispatches (viewable in perfetto/tensorboard), stopped at process
-    exit.  Off (and free) when unset."""
-    global _JAX_TRACE_DIR
-    path = os.environ.get("REPRO_JAX_TRACE_DIR")
-    if not path or _JAX_TRACE_DIR is not None:
-        return
-    jax.profiler.start_trace(path)
-    _JAX_TRACE_DIR = path
-    import atexit
-
-    atexit.register(jax.profiler.stop_trace)
 
 
 def _topo_key(topo: Topology, traced_cap: bool = False) -> tuple:
@@ -176,10 +164,12 @@ def _gated_b1(topo: Topology, cfg: SimConfig, W: int, F_pad: int, A: int,
 
 def _compiled(topo: Topology, cfg: SimConfig, W: int, F_pad: int, A: int,
               n_steps: int, batch: int, ops_sig: tuple = (),
-              cap_seg_steps: int = 0, cap_rows: int = 1, record=None):
-    """``ops_sig`` flags the traced operands after (trace_arrays, finish0)
-    in the fixed order (capacity, loss, reorder) — e.g. (True, False, True)
-    = capacity + reorder.  ``cap_seg_steps`` and ``cap_rows`` (K of a 2-D
+              cap_seg_steps: int = 0, cap_rows: int = 1, record=None,
+              args: tuple = ()):
+    """``args`` are the call's arguments: a build stores their abstract
+    values for ``op_phases``.  ``ops_sig`` flags the traced operands after
+    (trace_arrays, finish0) in the fixed order (capacity, loss, reorder) —
+    e.g. (True, False, True) = capacity + reorder.  ``cap_seg_steps`` and ``cap_rows`` (K of a 2-D
     schedule) are static shape/stride facts that must key the executable
     alongside the shapes.  ``record`` (hashable ``obs.RecordSpec`` or None)
     keys the executable too: the ring buffer's shapes are a pure function
@@ -207,10 +197,31 @@ def _compiled(topo: Topology, cfg: SimConfig, W: int, F_pad: int, A: int,
             fn = jax.jit(jax.vmap(core_kw, in_axes=in_axes),
                          donate_argnums=(1,))
         _JIT_CACHE[key] = fn
+        # no sharding: the call's arguments are uncommitted, and a lowering
+        # with one would miss the executable's cache entry
+        _ABSTRACT[key] = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
         _CACHE_STATS["builds"] += 1
     else:
         _CACHE_STATS["hits"] += 1
     return fn
+
+
+def op_phases() -> dict:
+    """``{HLO module name: {instruction: phase}}`` for the built
+    single-device executables (``obs.scopes.op_phases`` of each one's
+    optimized HLO), so a profiler trace's operations can be summed per
+    phase of the step.  A trace names modules, not executables: a module
+    name that two executables share with different maps maps to None.
+    Each executable is lowered and compiled again from the abstract
+    arguments stored at its build; once it has run, that is a hit in JAX's
+    in-memory caches, with no XLA compile."""
+    out: dict = {}
+    for key, abstract in _ABSTRACT.items():
+        text = _JIT_CACHE[key].lower(*abstract).compile().as_text()
+        name, phases = scopes.module_name(text), scopes.op_phases(text)
+        out[name] = phases if out.get(name, phases) == phases else None
+    return out
 
 
 def sweep_devices() -> int:
@@ -325,11 +336,15 @@ def batch_mode() -> str:
     return "persim" if jax.default_backend() == "cpu" else "vmap"
 
 
-def _trace_span(name: str = "repro.sweep.dispatch"):
-    """``jax.profiler`` annotation around a leaf dispatch: when a device
-    trace is being captured (REPRO_JAX_TRACE_DIR -> ``start_trace``), the
-    sweep executions show up as named spans in perfetto/tensorboard.
-    Near-free when no trace is active."""
+def _trace_span(name: str):
+    """``jax.profiler`` host span, on the profiler's clock with the device
+    trace; near-free when no trace is being captured.  A ``run_batch``
+    call records, in order: ``repro.sweep.prep`` (sorting, bucketing,
+    window and admission-lane planning, padding, stacking and the
+    host-to-device transfer), ``repro.sweep.dispatch`` (the executable call
+    alone), ``repro.sweep.fetch`` (the host waiting for spill / finish /
+    cnp / ff) and ``repro.sweep.unpack`` (results and per-sim outputs);
+    the last three once per (shape bucket, spill retry)."""
     return jax.profiler.TraceAnnotation(name)
 
 
@@ -353,35 +368,8 @@ def _dispatch(topo, cfg, W, F_pad, A, n_steps, stacked, B, capacity=None,
         "loss operand requires an explicit capacity operand"
     assert reorder is None or capacity is not None, \
         "reorder operand requires an explicit capacity operand"
-    ops = () if capacity is None else (jnp.asarray(capacity, jnp.float32),)
-    if loss is not None:
-        ops = ops + (jnp.asarray(loss, jnp.float32),)
-    if reorder is not None:
-        ops = ops + (jnp.asarray(reorder, jnp.float32),)
-    ops_sig = (capacity is not None, loss is not None, reorder is not None)
-    cap_rows = ops[0].shape[0] if ops and ops[0].ndim == 2 else 1
     D = sweep_devices()
-    if D > 1 and B > 1:
-        D = min(D, B)
-        Bp = -(-B // D) * D
-        if Bp > B:
-            stacked = tuple(
-                np.concatenate([a, np.repeat(a[-1:], Bp - B, axis=0)])
-                for a in stacked
-            )
-        per = Bp // D
-        shaped = tuple(
-            jnp.asarray(a.reshape((D, per) + a.shape[1:])) for a in stacked
-        )
-        fn = _compiled_sharded(topo, cfg, W, F_pad, A, n_steps, per, D,
-                               ops_sig, cap_seg_steps, cap_rows, record)
-        finish0 = jnp.full((D, per, F_pad), jnp.inf, jnp.float32)
-        with _trace_span():
-            out = fn(shaped, finish0, *ops)
-        return jax.tree.map(
-            lambda a: jnp.reshape(a, (Bp,) + a.shape[2:])[:B], out
-        )
-    if B > 1 and batch_mode() == "persim":
+    if D == 1 and B > 1 and batch_mode() == "persim":
         # every sim in the bucket shares (W, F_pad, A) -> ONE compiled B=1
         # program serves the whole loop
         parts = [
@@ -391,11 +379,38 @@ def _dispatch(topo, cfg, W, F_pad, A, n_steps, stacked, B, capacity=None,
             for i in range(B)
         ]
         return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
-    fn = _compiled(topo, cfg, W, F_pad, A, n_steps, B, ops_sig, cap_seg_steps,
-                   cap_rows, record)
-    finish0 = jnp.full((B, F_pad), jnp.inf, jnp.float32)
-    with _trace_span():
-        return fn(tuple(jnp.asarray(a) for a in stacked), finish0, *ops)
+    sharded = D > 1 and B > 1
+    with _trace_span("repro.sweep.prep"):
+        ops = tuple(jnp.asarray(x, jnp.float32) for x in (capacity, loss, reorder)
+                    if x is not None)
+        ops_sig = (capacity is not None, loss is not None, reorder is not None)
+        cap_rows = ops[0].shape[0] if ops and ops[0].ndim == 2 else 1
+        if sharded:
+            D = min(D, B)
+            Bp = -(-B // D) * D
+            if Bp > B:
+                stacked = tuple(
+                    np.concatenate([a, np.repeat(a[-1:], Bp - B, axis=0)])
+                    for a in stacked
+                )
+            per = Bp // D
+            args = (tuple(jnp.asarray(a.reshape((D, per) + a.shape[1:]))
+                          for a in stacked),
+                    jnp.full((D, per, F_pad), jnp.inf, jnp.float32)) + ops
+            fn = _compiled_sharded(topo, cfg, W, F_pad, A, n_steps, per, D,
+                                   ops_sig, cap_seg_steps, cap_rows, record)
+        else:
+            args = (tuple(jnp.asarray(a) for a in stacked),
+                    jnp.full((B, F_pad), jnp.inf, jnp.float32)) + ops
+            fn = _compiled(topo, cfg, W, F_pad, A, n_steps, B, ops_sig,
+                           cap_seg_steps, cap_rows, record, args)
+    with _trace_span("repro.sweep.dispatch"):
+        out = fn(*args)
+    if sharded:
+        return jax.tree.map(
+            lambda a: jnp.reshape(a, (Bp,) + a.shape[2:])[:B], out
+        )
+    return out
 
 
 def _run_group(topo, cfg, prepped, n_steps, window_slots, capacity=None,
@@ -407,60 +422,60 @@ def _run_group(topo, cfg, prepped, n_steps, window_slots, capacity=None,
     rerun with a window re-planned from the concurrency it actually
     exhibited.  Spill-free sims keep their first-run results — only the
     offenders pay the retry."""
-    F_pad = _f_bucket(max(F for (_, _, F) in prepped))
-    if window_slots is not None:
-        # explicit window: honor it exactly (tests probe the retry path)
-        W = max(8, min(int(window_slots), F_pad))
-    else:
-        W = min(plan_window(topo, [], scheme=cfg.scheme,
-                            sorted_arrays=[a for (a, _, _) in prepped]), F_pad)
-    A = _round_up(max(compact.max_admits_per_step(a[1], a[5], cfg.dt)
-                      for (a, _, _) in prepped), 32)
-    A = min(A, F_pad)
-    padded = [compact.pad_trace_arrays(a, F_pad) for (a, _, _) in prepped]
+    with _trace_span("repro.sweep.prep"):
+        F_pad = _f_bucket(max(F for (_, _, F) in prepped))
+        if window_slots is not None:
+            # explicit window: honor it exactly (tests probe the retry path)
+            W = max(8, min(int(window_slots), F_pad))
+        else:
+            W = min(plan_window(topo, [], scheme=cfg.scheme,
+                                sorted_arrays=[a for (a, _, _) in prepped]), F_pad)
+        A = _round_up(max(compact.max_admits_per_step(a[1], a[5], cfg.dt)
+                          for (a, _, _) in prepped), 32)
+        A = min(A, F_pad)
+        padded = [compact.pad_trace_arrays(a, F_pad) for (a, _, _) in prepped]
     results: list = [None] * len(prepped)
     outs_list: list = [None] * len(prepped)
     pending = list(range(len(prepped)))
     while pending:
-        stacked = tuple(
-            np.stack([padded[i][k] for i in pending])
-            for k in range(len(padded[0]))
-        )
-        t0 = time.time()
+        with _trace_span("repro.sweep.prep"):
+            stacked = tuple(
+                np.stack([padded[i][k] for i in pending])
+                for k in range(len(padded[0]))
+            )
         out = _dispatch(
             topo, cfg, W, F_pad, A, n_steps, stacked, len(pending), capacity,
             loss, cap_seg_steps, record, reorder)
         finish, cnp, spill, ff, outs = out[:5]
         ring = out[5] if len(out) > 5 else None
-        spill = np.asarray(spill)
-        finish = np.asarray(finish)
-        cnp = np.asarray(cnp)
-        ff = np.asarray(ff)
-        if os.environ.get("REPRO_SWEEP_DEBUG"):
-            print(f"# sweep {cfg.scheme} B={len(pending)} F_pad={F_pad} W={W} "
-                  f"A={A} spill={spill.tolist()} wall={time.time()-t0:.1f}s",
-                  flush=True)
+        with _trace_span("repro.sweep.fetch"):
+            spill = np.asarray(spill)
+            finish = np.asarray(finish)
+            cnp = np.asarray(cnp)
+            ff = np.asarray(ff)
         still, still_rows = [], []
-        for b, i in enumerate(pending):
-            if spill[b] == 0 or W >= F_pad:
-                _, inv, F = prepped[i]
-                results[i] = compact.CompactResult(
-                    finish=finish[b, :F][inv], cnp_pkts=cnp[b],
-                    spill_steps=int(spill[b]), window_slots=W,
-                    ff_steps=int(ff[b]),
-                    ring=None if ring is None
-                    else jax.tree.map(lambda a, b=b: a[b], ring),
-                )
-                outs_list[i] = jax.tree.map(lambda a, b=b: a[b], outs)
-            else:
-                still.append(i)
-                still_rows.append(b)
+        with _trace_span("repro.sweep.unpack"):
+            for b, i in enumerate(pending):
+                if spill[b] == 0 or W >= F_pad:
+                    _, inv, F = prepped[i]
+                    results[i] = compact.CompactResult(
+                        finish=finish[b, :F][inv], cnp_pkts=cnp[b],
+                        spill_steps=int(spill[b]), window_slots=W,
+                        ff_steps=int(ff[b]),
+                        ring=None if ring is None
+                        else jax.tree.map(lambda a, b=b: a[b], ring),
+                    )
+                    outs_list[i] = jax.tree.map(lambda a, b=b: a[b], outs)
+                else:
+                    still.append(i)
+                    still_rows.append(b)
         pending = still
         if pending:
             _OBS_STATS["spill_retries"] += 1
-            seen = _observed_concurrency(
-                [prepped[i] for i in pending], finish[still_rows], n_steps * cfg.dt
-            )
+            with _trace_span("repro.sweep.prep"):
+                seen = _observed_concurrency(
+                    [prepped[i] for i in pending], finish[still_rows],
+                    n_steps * cfg.dt)
             W = min(max(W * 2, _round_up(int(seen * 1.2) + 64, W_BUCKET)), F_pad)
             A = min(A * 2, F_pad)
     return results, outs_list
@@ -506,14 +521,14 @@ def run_batch(
     and ``reorder=None`` traces the identical pre-flowcell program."""
     assert traces, "empty sweep"
     enable_compile_cache()
-    _maybe_start_jax_trace()
     if (loss is not None or reorder is not None) and capacity is None:
         capacity = np.asarray(topo.capacity)
-    prepped = [compact.sort_trace(t) for t in traces]
+    with _trace_span("repro.sweep.prep"):
+        prepped = [compact.sort_trace(t) for t in traces]
+        groups: dict[int, list[int]] = {}
+        for i, (_, _, F) in enumerate(prepped):
+            groups.setdefault(_f_bucket(F), []).append(i)
     n_steps = int(round(cfg.duration_s / cfg.dt))
-    groups: dict[int, list[int]] = {}
-    for i, (_, _, F) in enumerate(prepped):
-        groups.setdefault(_f_bucket(F), []).append(i)
     results: list = [None] * len(traces)
     outs_list: list = [None] * len(traces)
     for idxs in groups.values():

@@ -359,20 +359,6 @@ print("SHARDED_OK")
     assert "SHARDED_OK" in out.stdout
 
 
-def test_profile_phases_smoke():
-    """--profile machinery: every phase times out positive and the fused
-    step is reported alongside."""
-    from repro.netsim import profile
-
-    topo = small_topo()
-    trace = small_trace(topo, dur=0.5e-3)
-    cfg = engine.SimConfig(scheme="seqbalance", duration_s=2e-3)
-    times = profile.profile_phases(topo, cfg, trace, warm_steps=20, iters=3)
-    for phase in ("admit", "cascade", "dcqcn", "finish", "step_fused"):
-        assert times[phase] > 0.0
-    assert times["window_slots"] >= 8
-
-
 def test_max_concurrency_bound_sane():
     topo = small_topo()
     trace = small_trace(topo)
