@@ -66,6 +66,14 @@ def hash_five_tuple(
     return fmix32(h ^ jnp.uint32(4 * 4))
 
 
+def double_hash_probe(h1: jax.Array, h2: jax.Array, i: jax.Array, n_paths: int) -> jax.Array:
+    """Probe ``i`` of the double-hash sequence, (h1 + i * (2*h2+1)) mod n_paths,
+    in uint32 with its wrap-around.  ``h1``, ``h2`` and ``i`` broadcast; int32."""
+    stride = h2.astype(jnp.uint32) * jnp.uint32(2) + jnp.uint32(1)
+    seq = h1.astype(jnp.uint32) + jnp.asarray(i).astype(jnp.uint32) * stride
+    return (seq % jnp.uint32(n_paths)).astype(jnp.int32)
+
+
 def double_hash_sequence(h1: jax.Array, h2: jax.Array, n_probes: int, n_paths: int) -> jax.Array:
     """Probe sequence path_i = (h1 + i * (2*h2+1)) mod n_paths.
 
@@ -74,6 +82,4 @@ def double_hash_sequence(h1: jax.Array, h2: jax.Array, n_probes: int, n_paths: i
     non-power-of-two path counts it still cycles well.  Shape: [..., n_probes].
     """
     i = jnp.arange(n_probes, dtype=jnp.uint32)
-    stride = (h2.astype(jnp.uint32) * jnp.uint32(2) + jnp.uint32(1))[..., None]
-    seq = h1.astype(jnp.uint32)[..., None] + i * stride
-    return (seq % jnp.uint32(n_paths)).astype(jnp.int32)
+    return double_hash_probe(h1[..., None], h2[..., None], i, n_paths)

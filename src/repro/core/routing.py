@@ -37,13 +37,20 @@ def select_paths(
         max_probes = n_paths
     h1 = hashing.hash_five_tuple(src, dst, sport, dport, salt=salt)
     h2 = hashing.hash_five_tuple(src, dst, sport, dport, salt=salt + 0x5EED)
-    probes = hashing.double_hash_sequence(h1, h2, max_probes, n_paths)  # [..., P]
-    probe_inactive = jnp.take_along_axis(inactive, probes, axis=-1)  # [..., P]
+    probes = hashing.double_hash_sequence(h1, h2, max_probes, n_paths)  # [..., K]
+    # inactive[probes] without an element gather (serialised on the TPU, one
+    # element per ~13 ns): compare each probe with every path id and OR the
+    # matches' marks.  Paths sit on the second-minor axis, so the OR runs
+    # across vregs, not lanes; XLA fuses the [..., P, K] compare into it.
+    path_ids = jnp.arange(n_paths, dtype=jnp.int32)[:, None]  # [P, 1]
+    probe_inactive = jnp.any(
+        (probes[..., None, :] == path_ids) & inactive[..., :, None], axis=-2)
     # index of first ACTIVE probe; if none, fall back to probe 0 (= plain hash)
     first_active = jnp.argmax(~probe_inactive, axis=-1)
     any_active = jnp.any(~probe_inactive, axis=-1)
     pick = jnp.where(any_active, first_active, 0)
-    return jnp.take_along_axis(probes, pick[..., None], axis=-1)[..., 0]
+    # the chosen probe, recomputed as double_hash_sequence computes it
+    return hashing.double_hash_probe(h1, h2, pick, n_paths)
 
 
 def ecmp_paths(
