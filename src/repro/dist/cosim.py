@@ -26,6 +26,11 @@ Two contracts make the loop cheap and honest:
     about faults, and one slot per flow makes spill — and therefore
     shape-changing retries — impossible), so trace shapes never drift.
 
+Each epoch runs inside the host span ``repro.cosim.epoch`` and its stages
+inside ``repro.cosim.trace`` / ``.sim`` / ``.feedback`` / ``.record``
+(``obs.scopes.span``), so a profiler trace splits an epoch's wall time
+into host planning and device work; the spans change no result.
+
 ``run_cosim_grid`` fans a (scheme x ring size x fault schedule x seed)
 grid through ``netsim.sweep.run_jobs`` — including paper-scale
 ``three_tier`` (320 hosts) — one callable job per grid point.
@@ -41,6 +46,7 @@ import numpy as np
 
 from repro.dist import netfeed
 from repro.dist.elastic import LinkHealth
+from repro.obs import scopes
 
 
 # ---------------------------------------------------------- fault schedule
@@ -586,262 +592,268 @@ def run_cosim(
     W = window_slots
     try:
         for epoch in range(start_epoch, epochs):
-            t_ep = time.time()  # epoch wall-clock span for the flight log
-            # ------------------------------------- safe-mode plan selection
-            # entering state of the watchdog decides THIS epoch's conduct:
-            # blind planners don't steer — run everything-active, unsteered
-            in_safe = watchdog is not None and watchdog.safe_mode
-            if in_safe:
-                run_plan = collectives.PathPlan(
-                    n_chunks=n_chunks, directions=tuple(health.directions),
-                    inactive=None, wire_dtype=wire_dtype,
-                    version=plan.version)
-            else:
-                run_plan = plan
+            with scopes.span("repro.cosim.epoch"):
+                t_ep = time.time()  # epoch wall-clock span for the flight log
+                with scopes.span("repro.cosim.trace"):
+                    # ------------------------------------- safe-mode plan selection
+                    # entering state of the watchdog decides THIS epoch's conduct:
+                    # blind planners don't steer — run everything-active, unsteered
+                    in_safe = watchdog is not None and watchdog.safe_mode
+                    if in_safe:
+                        run_plan = collectives.PathPlan(
+                            n_chunks=n_chunks, directions=tuple(health.directions),
+                            inactive=None, wire_dtype=wire_dtype,
+                            version=plan.version)
+                    else:
+                        run_plan = plan
 
-            # -------------------------------------------- fault state
-            if campaign is not None:
-                cap = campaign.capacity_schedule(topo, epoch)  # [K, nl+1]
-                for ev in faults:  # epoch-level faults compose on top
-                    if ev.active(epoch):
-                        cap[:, list(ev.links)] *= np.float32(ev.scale)
-                # adaptive dt: align the segment stride to the scan-chunk
-                # grid so no chunk straddles a capacity edge (the quiescence
-                # predicate would refuse to fast-forward it); fixed dt keeps
-                # the PR 6 uniform stride bit-identical
-                K_chunk, _, _ = compact.plan_chunks(cfg, n_steps)
-                cap_seg = campaign.seg_steps(
-                    n_steps, align=K_chunk if cfg.adaptive else 1)
-                loss = campaign.loss_at(topo, epoch)
-                # congestion reporting sees the epoch's WORST capacity: a
-                # link that flapped at all this epoch reads as degraded
-                cap_report = cap.min(axis=0)
-                slowdowns = campaign.straggler_slowdowns(epoch)
-            else:
-                cap = capacity_at(topo, faults, epoch)
-                cap_seg, loss, cap_report = 0, None, cap
-                slowdowns = {}
+                    # -------------------------------------------- fault state
+                    if campaign is not None:
+                        cap = campaign.capacity_schedule(topo, epoch)  # [K, nl+1]
+                        for ev in faults:  # epoch-level faults compose on top
+                            if ev.active(epoch):
+                                cap[:, list(ev.links)] *= np.float32(ev.scale)
+                        # adaptive dt: align the segment stride to the scan-chunk
+                        # grid so no chunk straddles a capacity edge (the quiescence
+                        # predicate would refuse to fast-forward it); fixed dt keeps
+                        # the uniform stride bit-identical
+                        K_chunk, _, _ = compact.plan_chunks(cfg, n_steps)
+                        cap_seg = campaign.seg_steps(
+                            n_steps, align=K_chunk if cfg.adaptive else 1)
+                        loss = campaign.loss_at(topo, epoch)
+                        # congestion reporting sees the epoch's WORST capacity: a
+                        # link that flapped at all this epoch reads as degraded
+                        cap_report = cap.min(axis=0)
+                        slowdowns = campaign.straggler_slowdowns(epoch)
+                    else:
+                        cap = capacity_at(topo, faults, epoch)
+                        cap_seg, loss, cap_report = 0, None, cap
+                        slowdowns = {}
 
-            # -------------------------------------------- stragglers
-            strag_quar: tuple[int, ...] = ()
-            if policy is not None:
-                for i in range(n):
-                    policy.observe(i, gap * slowdowns.get(i, 1.0))
-                strag_quar = policy.quarantined()
-            eff = max([slowdowns.get(i, 1.0) for i in range(n)
-                       if i not in strag_quar] or [1.0])
-            gap_e = gap * eff  # slowest non-quarantined rank gates the ring
+                    # -------------------------------------------- stragglers
+                    strag_quar: tuple[int, ...] = ()
+                    if policy is not None:
+                        for i in range(n):
+                            policy.observe(i, gap * slowdowns.get(i, 1.0))
+                        strag_quar = policy.quarantined()
+                    eff = max([slowdowns.get(i, 1.0) for i in range(n)
+                               if i not in strag_quar] or [1.0])
+                    gap_e = gap * eff  # slowest non-quarantined rank gates the ring
 
-            # ------------------------------- trace (+ in-epoch replanning)
-            steer_p = topo.n_paths if steer and not in_safe else None
-            onset = campaign.midepoch_onset(topo, epoch) if campaign else None
-            replan_round = -1
-            if onset is not None and replan and steer and not in_safe \
-                    and onset.paths:
-                t_detect = onset.frac * duration_s + (
-                    detect_delay_s if detect_delay_s is not None else 2 * gap_e)
-                r_cut = int(math.ceil(t_detect / gap_e))
-                if 0 < r_cut < rounds:
-                    replan_round = r_cut
-            if replan_round > 0:
-                # the fault is observed mid-collective: report it NOW so
-                # both this epoch's tail and the next plan route around it
-                for p in onset.paths:
-                    health.report_slow(p, epoch)
-                inact2 = tuple(d or (p in set(onset.paths))
-                               for p, d in enumerate(plan.inactive))
-                pinned = collectives.PinnedPlan(
-                    n_chunks=n_chunks, directions=tuple(plan.directions),
-                    inactive=inact2,
-                    paths=collectives.replan_chunk_paths(
-                        plan.chunk_paths(), tuple(plan.directions), inact2),
-                    wire_dtype=wire_dtype)
-                # steering targets: surviving QPs keep their fid (their
-                # stream stays on its path — no reorder); only QPs whose
-                # fabric path died re-steer, round-robin over survivors,
-                # falling back to the primary path when none survive
-                active0 = [p for p, d in enumerate(plan.inactive)
-                           if not d] or [0]
-                tgt = np.array(
-                    [[active0[(i * n_chunks + c) % len(active0)]
-                      for i in range(n)] for c in range(n_chunks)], np.int32)
-                dead = set(onset.paths)
-                surv = [p for p in active0 if p not in dead] or [0]
-                tgt_b, k = tgt.copy(), 0
-                for c in range(n_chunks):
-                    for i in range(n):
-                        if int(tgt[c, i]) in dead:
-                            tgt_b[c, i] = surv[k % len(surv)]
-                            k += 1
-                tr_a = workloads.collective_trace(
-                    _fc(plan), hosts, size_bytes, link_bw=fabric_bw,
-                    round_gap_s=gap_e, rounds=replan_round, seed=seed,
-                    steer_paths=steer_p, steer_targets=tgt)
-                tr_b = workloads.collective_trace(
-                    _fc(pinned), hosts, size_bytes, link_bw=fabric_bw,
-                    round_gap_s=gap_e, rounds=rounds - replan_round,
-                    start_s=replan_round * gap_e, seed=seed,
-                    steer_paths=steer_p, steer_targets=tgt_b)
-                trace = workloads.merge_traces(tr_a, tr_b)
-            else:
-                trace = workloads.collective_trace(
-                    _fc(run_plan), hosts, size_bytes, link_bw=fabric_bw,
-                    round_gap_s=gap_e, seed=seed, steer_paths=steer_p)
-            if W is None:
-                W = int(trace.valid.sum())  # spill-proof: one slot per flow
+                    # ------------------------------- trace (+ in-epoch replanning)
+                    steer_p = topo.n_paths if steer and not in_safe else None
+                    onset = campaign.midepoch_onset(topo, epoch) if campaign else None
+                    replan_round = -1
+                    if onset is not None and replan and steer and not in_safe \
+                            and onset.paths:
+                        t_detect = onset.frac * duration_s + (
+                            detect_delay_s if detect_delay_s is not None else 2 * gap_e)
+                        r_cut = int(math.ceil(t_detect / gap_e))
+                        if 0 < r_cut < rounds:
+                            replan_round = r_cut
+                    if replan_round > 0:
+                        # the fault is observed mid-collective: report it NOW so
+                        # both this epoch's tail and the next plan route around it
+                        for p in onset.paths:
+                            health.report_slow(p, epoch)
+                        inact2 = tuple(d or (p in set(onset.paths))
+                                       for p, d in enumerate(plan.inactive))
+                        pinned = collectives.PinnedPlan(
+                            n_chunks=n_chunks, directions=tuple(plan.directions),
+                            inactive=inact2,
+                            paths=collectives.replan_chunk_paths(
+                                plan.chunk_paths(), tuple(plan.directions), inact2),
+                            wire_dtype=wire_dtype)
+                        # steering targets: surviving QPs keep their fid (their
+                        # stream stays on its path — no reorder); only QPs whose
+                        # fabric path died re-steer, round-robin over survivors,
+                        # falling back to the primary path when none survive
+                        active0 = [p for p, d in enumerate(plan.inactive)
+                                   if not d] or [0]
+                        tgt = np.array(
+                            [[active0[(i * n_chunks + c) % len(active0)]
+                              for i in range(n)] for c in range(n_chunks)], np.int32)
+                        dead = set(onset.paths)
+                        surv = [p for p in active0 if p not in dead] or [0]
+                        tgt_b, k = tgt.copy(), 0
+                        for c in range(n_chunks):
+                            for i in range(n):
+                                if int(tgt[c, i]) in dead:
+                                    tgt_b[c, i] = surv[k % len(surv)]
+                                    k += 1
+                        tr_a = workloads.collective_trace(
+                            _fc(plan), hosts, size_bytes, link_bw=fabric_bw,
+                            round_gap_s=gap_e, rounds=replan_round, seed=seed,
+                            steer_paths=steer_p, steer_targets=tgt)
+                        tr_b = workloads.collective_trace(
+                            _fc(pinned), hosts, size_bytes, link_bw=fabric_bw,
+                            round_gap_s=gap_e, rounds=rounds - replan_round,
+                            start_s=replan_round * gap_e, seed=seed,
+                            steer_paths=steer_p, steer_targets=tgt_b)
+                        trace = workloads.merge_traces(tr_a, tr_b)
+                    else:
+                        trace = workloads.collective_trace(
+                            _fc(run_plan), hosts, size_bytes, link_bw=fabric_bw,
+                            round_gap_s=gap_e, seed=seed, steer_paths=steer_p)
+                    if W is None:
+                        W = int(trace.valid.sum())  # spill-proof: one slot per flow
 
-            # -------------------------------------------------- simulate
-            b0 = sweep.cache_stats()["builds"]
-            result, outs = sweep.run_one(topo, cfg, trace, capacity=cap,
-                                         loss=loss, cap_seg_steps=cap_seg,
-                                         window_slots=W, record=record,
-                                         reorder=reorder_budget)
-            new_builds = sweep.cache_stats()["builds"] - b0
-            insim = None
-            if record is not None and getattr(result, "ring", None) is not None:
-                from repro import obs
+                with scopes.span("repro.cosim.sim"):
+                    # -------------------------------------------------- simulate
+                    b0 = sweep.cache_stats()["builds"]
+                    result, outs = sweep.run_one(topo, cfg, trace, capacity=cap,
+                                                 loss=loss, cap_seg_steps=cap_seg,
+                                                 window_slots=W, record=record,
+                                                 reorder=reorder_budget)
+                    new_builds = sweep.cache_stats()["builds"] - b0
+                    insim = None
+                    if record is not None and getattr(result, "ring", None) is not None:
+                        from repro import obs
 
-                insim = obs.epoch_summary(record, obs.drain(record, result.ring))
+                        insim = obs.epoch_summary(record, obs.drain(record, result.ring))
 
-            # ------------------------------------ congestion feedback path
-            n_sent = n_delivered = n_admitted = n_stale = n_dup = -1
-            if telemetry is None:
-                # perfect channel: the legacy direct path, bit-identical
-                slow = netfeed.report_congestion(
-                    health, topo, outs, step=epoch, overload=overload,
-                    capacity=cap_report, loss=loss)
-            else:
-                observed = netfeed.observe_congestion(
-                    topo, outs, overload=overload, capacity=cap_report,
-                    loss=loss)
-                for p in observed:
-                    telemetry.send(("slow", int(p)), epoch)
-                for leaf in range(topo.n_leaf):  # liveness heartbeats
-                    telemetry.send(("hb", int(leaf)), epoch)
-                n_sent = len(observed) + topo.n_leaf
-                batch = telemetry.deliver(epoch)
-                n_delivered = len(batch)
-                n_admitted = n_stale = n_dup = 0
-                admitted_slow: list[int] = []
-                for payload, origin in batch:
-                    if payload[0] == "slow":
-                        verdict = health.admit_report(
-                            int(payload[1]), origin, epoch)
-                        if verdict == "admitted":
-                            n_admitted += 1
-                            admitted_slow.append(int(payload[1]))
-                        elif verdict == "stale":
-                            n_stale += 1
-                        else:
-                            n_dup += 1
-                    else:  # heartbeat: same staleness gate, no health state
-                        if staleness_bound is not None \
-                                and epoch - origin > staleness_bound:
-                            n_stale += 1
-                        else:
-                            n_admitted += 1
-                watchdog.observe(n_admitted)
-                slow = tuple(dict.fromkeys(admitted_slow))
+                with scopes.span("repro.cosim.feedback"):
+                    # ------------------------------------ congestion feedback path
+                    n_sent = n_delivered = n_admitted = n_stale = n_dup = -1
+                    if telemetry is None:
+                        # perfect channel: the legacy direct path, bit-identical
+                        slow = netfeed.report_congestion(
+                            health, topo, outs, step=epoch, overload=overload,
+                            capacity=cap_report, loss=loss)
+                    else:
+                        observed = netfeed.observe_congestion(
+                            topo, outs, overload=overload, capacity=cap_report,
+                            loss=loss)
+                        for p in observed:
+                            telemetry.send(("slow", int(p)), epoch)
+                        for leaf in range(topo.n_leaf):  # liveness heartbeats
+                            telemetry.send(("hb", int(leaf)), epoch)
+                        n_sent = len(observed) + topo.n_leaf
+                        batch = telemetry.deliver(epoch)
+                        n_delivered = len(batch)
+                        n_admitted = n_stale = n_dup = 0
+                        admitted_slow: list[int] = []
+                        for payload, origin in batch:
+                            if payload[0] == "slow":
+                                verdict = health.admit_report(
+                                    int(payload[1]), origin, epoch)
+                                if verdict == "admitted":
+                                    n_admitted += 1
+                                    admitted_slow.append(int(payload[1]))
+                                elif verdict == "stale":
+                                    n_stale += 1
+                                else:
+                                    n_dup += 1
+                            else:  # heartbeat: same staleness gate, no health state
+                                if staleness_bound is not None \
+                                        and epoch - origin > staleness_bound:
+                                    n_stale += 1
+                                else:
+                                    n_admitted += 1
+                        watchdog.observe(n_admitted)
+                        slow = tuple(dict.fromkeys(admitted_slow))
 
-            # ------------------------------------ versioned plan application
-            next_plan = health.plan(epoch + 1, n_chunks=n_chunks,
-                                    wire_dtype=wire_dtype)
-            applied, took = collectives.apply_plan(plan, next_plan)
-            if not took:
-                plan_refused += 1  # a genuinely newer plan was refused: bug
-            # the cross-version no-reordering invariant, asserted live: a
-            # reordered (older) or duplicated delivery must be refused and
-            # leave the applied table untouched
-            stale_applied, took_stale = collectives.apply_plan(applied, plan)
-            assert stale_applied is applied and not took_stale, \
-                (applied.version, plan.version)
-            dup_applied, took_dup = collectives.apply_plan(applied, applied)
-            assert dup_applied is applied and not took_dup
-            churn = sum(int(a != b)
-                        for a, b in zip(plan.inactive, applied.inactive))
-            fct, completion = metrics.fct_samples(result, trace,
-                                                  horizon_s=duration_s)
-            imb = metrics.throughput_imbalance(
-                outs, sample_every=imbalance_sample_every,
-                trace_stride=cfg.uplink_sample_every)
-            rec = EpochRecord(
-                epoch=epoch,
-                fct_p50_s=float(np.percentile(fct, 50)),
-                fct_p99_s=float(np.percentile(fct, 99)),
-                fct_mean_s=float(fct.mean()),
-                completion=completion,
-                imbalance_mean=float(imb.mean()) if imb.size else 0.0,
-                plan_churn=churn,
-                quarantined=tuple(
-                    p for p, d in enumerate(run_plan.inactive) if d),
-                reported_slow=tuple(slow),
-                spill_steps=int(result.spill_steps),
-                new_builds=new_builds,
-                fct=fct,
-                imbalance=imb,
-                replan_round=replan_round,
-                straggler_scale=float(eff),
-                straggler_quarantined=strag_quar,
-                safe_mode=in_safe,
-                plan_version=int(plan.version),
-                reports_sent=n_sent,
-                reports_delivered=n_delivered,
-                reports_admitted=n_admitted,
-                reports_stale=n_stale,
-                reports_duplicate=n_dup,
-                ff_steps=int(getattr(result, "ff_steps", 0)),
-                insim=insim,
-            )
-            records.append(rec)
-            plans.append(run_plan)
-            if journal_fh is not None:
-                import json
+                    # ------------------------------------ versioned plan application
+                    next_plan = health.plan(epoch + 1, n_chunks=n_chunks,
+                                            wire_dtype=wire_dtype)
+                    applied, took = collectives.apply_plan(plan, next_plan)
+                    if not took:
+                        plan_refused += 1  # a genuinely newer plan was refused: bug
+                    # the cross-version no-reordering invariant, asserted live: a
+                    # reordered (older) or duplicated delivery must be refused and
+                    # leave the applied table untouched
+                    stale_applied, took_stale = collectives.apply_plan(applied, plan)
+                    assert stale_applied is applied and not took_stale, \
+                        (applied.version, plan.version)
+                    dup_applied, took_dup = collectives.apply_plan(applied, applied)
+                    assert dup_applied is applied and not took_dup
 
-                journal_fh.write(json.dumps(dict(
-                    epoch=epoch,
-                    record=_rec_to_json(rec),
-                    plan_inactive=[bool(b) for b in run_plan.inactive],
-                    health=health.state(),
-                    straggler=policy.state() if policy is not None else None,
-                    telemetry=telemetry.state()
-                    if telemetry is not None else None,
-                    watchdog=watchdog.state()
-                    if watchdog is not None else None,
-                )) + "\n")
-                journal_fh.flush()
-            if fl is not None:
-                fa = list(campaign.activations(epoch)) if campaign else []
-                fa += [dict(kind="FaultEvent", links=list(ev.links),
-                            scale=ev.scale, start_epoch=ev.start_epoch,
-                            end_epoch=ev.end_epoch)
-                       for ev in faults if ev.active(epoch)]
-                fl.event(
-                    "epoch", epoch=epoch, t0_s=t_ep,
-                    dur_s=time.time() - t_ep, n_steps=n_steps,
-                    fct_p50_us=round(rec.fct_p50_s * 1e6, 3),
-                    fct_p99_us=round(rec.fct_p99_s * 1e6, 3),
-                    completion=round(completion, 5),
-                    plan_version=int(run_plan.version), plan_churn=churn,
-                    safe_mode=in_safe, replan_round=replan_round,
-                    quarantined=[int(p) for p in rec.quarantined],
-                    reported_slow=[int(p) for p in rec.reported_slow],
-                    straggler_quarantined=[int(i) for i in strag_quar],
-                    straggler_scale=float(eff),
-                    new_builds=new_builds,
-                    spill_steps=int(result.spill_steps),
-                    ff_steps=rec.ff_steps,
-                    reports=None if telemetry is None else dict(
-                        sent=n_sent, delivered=n_delivered,
-                        admitted=n_admitted, stale=n_stale, duplicate=n_dup),
-                    watchdog=watchdog.state() if watchdog is not None
-                    else None,
-                    sweep=sweep.obs_stats(),
-                    hot_uplinks=netfeed.hot_uplinks(
-                        topo, outs, capacity=cap_report),
-                    faults=fa,
-                    insim=insim,
-                )
-            plan = applied
+                with scopes.span("repro.cosim.record"):
+                    churn = sum(int(a != b)
+                                for a, b in zip(plan.inactive, applied.inactive))
+                    fct, completion = metrics.fct_samples(result, trace,
+                                                          horizon_s=duration_s)
+                    imb = metrics.throughput_imbalance(
+                        outs, sample_every=imbalance_sample_every,
+                        trace_stride=cfg.uplink_sample_every)
+                    rec = EpochRecord(
+                        epoch=epoch,
+                        fct_p50_s=float(np.percentile(fct, 50)),
+                        fct_p99_s=float(np.percentile(fct, 99)),
+                        fct_mean_s=float(fct.mean()),
+                        completion=completion,
+                        imbalance_mean=float(imb.mean()) if imb.size else 0.0,
+                        plan_churn=churn,
+                        quarantined=tuple(
+                            p for p, d in enumerate(run_plan.inactive) if d),
+                        reported_slow=tuple(slow),
+                        spill_steps=int(result.spill_steps),
+                        new_builds=new_builds,
+                        fct=fct,
+                        imbalance=imb,
+                        replan_round=replan_round,
+                        straggler_scale=float(eff),
+                        straggler_quarantined=strag_quar,
+                        safe_mode=in_safe,
+                        plan_version=int(plan.version),
+                        reports_sent=n_sent,
+                        reports_delivered=n_delivered,
+                        reports_admitted=n_admitted,
+                        reports_stale=n_stale,
+                        reports_duplicate=n_dup,
+                        ff_steps=int(getattr(result, "ff_steps", 0)),
+                        insim=insim,
+                    )
+                    records.append(rec)
+                    plans.append(run_plan)
+                    if journal_fh is not None:
+                        import json
+
+                        journal_fh.write(json.dumps(dict(
+                            epoch=epoch,
+                            record=_rec_to_json(rec),
+                            plan_inactive=[bool(b) for b in run_plan.inactive],
+                            health=health.state(),
+                            straggler=policy.state() if policy is not None else None,
+                            telemetry=telemetry.state()
+                            if telemetry is not None else None,
+                            watchdog=watchdog.state()
+                            if watchdog is not None else None,
+                        )) + "\n")
+                        journal_fh.flush()
+                    if fl is not None:
+                        fa = list(campaign.activations(epoch)) if campaign else []
+                        fa += [dict(kind="FaultEvent", links=list(ev.links),
+                                    scale=ev.scale, start_epoch=ev.start_epoch,
+                                    end_epoch=ev.end_epoch)
+                               for ev in faults if ev.active(epoch)]
+                        fl.event(
+                            "epoch", epoch=epoch, t0_s=t_ep,
+                            dur_s=time.time() - t_ep, n_steps=n_steps,
+                            fct_p50_us=round(rec.fct_p50_s * 1e6, 3),
+                            fct_p99_us=round(rec.fct_p99_s * 1e6, 3),
+                            completion=round(completion, 5),
+                            plan_version=int(run_plan.version), plan_churn=churn,
+                            safe_mode=in_safe, replan_round=replan_round,
+                            quarantined=[int(p) for p in rec.quarantined],
+                            reported_slow=[int(p) for p in rec.reported_slow],
+                            straggler_quarantined=[int(i) for i in strag_quar],
+                            straggler_scale=float(eff),
+                            new_builds=new_builds,
+                            spill_steps=int(result.spill_steps),
+                            ff_steps=rec.ff_steps,
+                            reports=None if telemetry is None else dict(
+                                sent=n_sent, delivered=n_delivered,
+                                admitted=n_admitted, stale=n_stale, duplicate=n_dup),
+                            watchdog=watchdog.state() if watchdog is not None
+                            else None,
+                            sweep=sweep.obs_stats(),
+                            hot_uplinks=netfeed.hot_uplinks(
+                                topo, outs, capacity=cap_report),
+                            faults=fa,
+                            insim=insim,
+                        )
+                plan = applied
         hist = CosimHistory(scheme=scheme, phi_steps=phi_steps,
                             duration_s=duration_s, records=records,
                             plans=plans, final_plan=plan, health=health,

@@ -176,7 +176,7 @@ def _compiled(topo: Topology, cfg: SimConfig, W: int, F_pad: int, A: int,
     of the spec, so recording costs exactly one extra program per (shape
     bucket, spec) and never a rebuild across epochs — the contract
     ``check_bench.py --obs`` gates."""
-    key = (_topo_key(topo, bool(ops_sig)), cfg, W, F_pad, A, n_steps, batch,
+    key = (_topo_key(topo, any(ops_sig)), cfg, W, F_pad, A, n_steps, batch,
            ops_sig, cap_seg_steps, cap_rows, record)
     fn = _JIT_CACHE.get(key)
     if fn is None:
@@ -242,7 +242,7 @@ def _compiled_sharded(topo: Topology, cfg: SimConfig, W: int, F_pad: int,
     (same program, same shapes — only the dispatch is parallel).  Traced
     operands (capacity [+ loss] [+ reorder]) are broadcast to every device
     (in_axes None)."""
-    key = (_topo_key(topo, bool(ops_sig)), cfg, W, F_pad, A, n_steps, per_dev,
+    key = (_topo_key(topo, any(ops_sig)), cfg, W, F_pad, A, n_steps, per_dev,
            n_dev, ops_sig, cap_seg_steps, cap_rows, record, "pmap")
     fn = _JIT_CACHE.get(key)
     if fn is None:
@@ -336,18 +336,6 @@ def batch_mode() -> str:
     return "persim" if jax.default_backend() == "cpu" else "vmap"
 
 
-def _trace_span(name: str):
-    """``jax.profiler`` host span, on the profiler's clock with the device
-    trace; near-free when no trace is being captured.  A ``run_batch``
-    call records, in order: ``repro.sweep.prep`` (sorting, bucketing,
-    window and admission-lane planning, padding, stacking and the
-    host-to-device transfer), ``repro.sweep.dispatch`` (the executable call
-    alone), ``repro.sweep.fetch`` (the host waiting for spill / finish /
-    cnp / ff) and ``repro.sweep.unpack`` (results and per-sim outputs);
-    the last three once per (shape bucket, spill retry)."""
-    return jax.profiler.TraceAnnotation(name)
-
-
 def _dispatch(topo, cfg, W, F_pad, A, n_steps, stacked, B, capacity=None,
               loss=None, cap_seg_steps=0, record=None, reorder=None):
     """Run a stacked [B, ...] batch, returning (finish, cnp, spill,
@@ -380,7 +368,7 @@ def _dispatch(topo, cfg, W, F_pad, A, n_steps, stacked, B, capacity=None,
         ]
         return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
     sharded = D > 1 and B > 1
-    with _trace_span("repro.sweep.prep"):
+    with scopes.span("repro.sweep.prep"):
         ops = tuple(jnp.asarray(x, jnp.float32) for x in (capacity, loss, reorder)
                     if x is not None)
         ops_sig = (capacity is not None, loss is not None, reorder is not None)
@@ -404,7 +392,7 @@ def _dispatch(topo, cfg, W, F_pad, A, n_steps, stacked, B, capacity=None,
                     jnp.full((B, F_pad), jnp.inf, jnp.float32)) + ops
             fn = _compiled(topo, cfg, W, F_pad, A, n_steps, B, ops_sig,
                            cap_seg_steps, cap_rows, record, args)
-    with _trace_span("repro.sweep.dispatch"):
+    with scopes.span("repro.sweep.dispatch"):
         out = fn(*args)
     if sharded:
         return jax.tree.map(
@@ -422,7 +410,7 @@ def _run_group(topo, cfg, prepped, n_steps, window_slots, capacity=None,
     rerun with a window re-planned from the concurrency it actually
     exhibited.  Spill-free sims keep their first-run results — only the
     offenders pay the retry."""
-    with _trace_span("repro.sweep.prep"):
+    with scopes.span("repro.sweep.prep"):
         F_pad = _f_bucket(max(F for (_, _, F) in prepped))
         if window_slots is not None:
             # explicit window: honor it exactly (tests probe the retry path)
@@ -438,7 +426,7 @@ def _run_group(topo, cfg, prepped, n_steps, window_slots, capacity=None,
     outs_list: list = [None] * len(prepped)
     pending = list(range(len(prepped)))
     while pending:
-        with _trace_span("repro.sweep.prep"):
+        with scopes.span("repro.sweep.prep"):
             stacked = tuple(
                 np.stack([padded[i][k] for i in pending])
                 for k in range(len(padded[0]))
@@ -448,13 +436,13 @@ def _run_group(topo, cfg, prepped, n_steps, window_slots, capacity=None,
             loss, cap_seg_steps, record, reorder)
         finish, cnp, spill, ff, outs = out[:5]
         ring = out[5] if len(out) > 5 else None
-        with _trace_span("repro.sweep.fetch"):
+        with scopes.span("repro.sweep.fetch"):
             spill = np.asarray(spill)
             finish = np.asarray(finish)
             cnp = np.asarray(cnp)
             ff = np.asarray(ff)
         still, still_rows = [], []
-        with _trace_span("repro.sweep.unpack"):
+        with scopes.span("repro.sweep.unpack"):
             for b, i in enumerate(pending):
                 if spill[b] == 0 or W >= F_pad:
                     _, inv, F = prepped[i]
@@ -472,7 +460,7 @@ def _run_group(topo, cfg, prepped, n_steps, window_slots, capacity=None,
         pending = still
         if pending:
             _OBS_STATS["spill_retries"] += 1
-            with _trace_span("repro.sweep.prep"):
+            with scopes.span("repro.sweep.prep"):
                 seen = _observed_concurrency(
                     [prepped[i] for i in pending], finish[still_rows],
                     n_steps * cfg.dt)
@@ -523,7 +511,7 @@ def run_batch(
     enable_compile_cache()
     if (loss is not None or reorder is not None) and capacity is None:
         capacity = np.asarray(topo.capacity)
-    with _trace_span("repro.sweep.prep"):
+    with scopes.span("repro.sweep.prep"):
         prepped = [compact.sort_trace(t) for t in traces]
         groups: dict[int, list[int]] = {}
         for i, (_, _, F) in enumerate(prepped):

@@ -14,8 +14,13 @@ arrays (sizes, arrival times, src/dst hosts).
 from __future__ import annotations
 
 import dataclasses
+import functools
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+
+from repro.core import hashing, routing
 
 # (size_bytes, cumulative_probability)
 WEBSEARCH_CDF = np.array(
@@ -188,25 +193,29 @@ def _ecmp_steered_fids(src: np.ndarray, dst: np.ndarray, base_fid: np.ndarray,
     against each other so they cannot drift silently.
 
     For each QP the candidate ids ``base + k * golden`` are hashed in one
-    vectorized sweep and the first hit wins; a QP with no hit in the ~32x
+    jitted sweep and the first hit wins; a QP with no hit in the ~32x
     oversampled candidate set (probability ~(1-1/P)^(32P) ~ 1e-14) keeps
     its base id."""
-    import jax.numpy as jnp
-
-    from repro.core import hashing, routing
-
     K = int(min(32 * n_paths, 16384))
     ks = np.arange(K, dtype=np.uint32) * np.uint32(0x9E3779B1)
-    cand = base_fid.astype(np.uint32)[:, None] + ks[None, :]  # [Q, K] wraps
-    sport = jnp.uint32(0xB000) + (hashing.fmix32(jnp.asarray(cand))
-                                  % jnp.uint32(0x3FFF))
+    k = np.asarray(_first_steered_candidate(
+        np.asarray(src).astype(np.uint32), np.asarray(dst).astype(np.uint32),
+        np.asarray(base_fid).astype(np.uint32), np.asarray(target_path, np.int32), ks,
+        n_paths=n_paths))
+    return base_fid.astype(np.uint32) + ks[k]
+
+
+@functools.partial(jax.jit, static_argnames="n_paths")
+def _first_steered_candidate(src, dst, base_fid, target_path, ks, n_paths: int):
+    """The index k into ``ks`` of each QP's first candidate id
+    ``base + ks[k]`` (uint32 wrap) that ECMP hashes onto its target path,
+    0 where none does.  One dispatch in place of one per hashing op."""
+    cand = base_fid[:, None] + ks[None, :]  # [Q, K] wraps
+    sport = jnp.uint32(0xB000) + (hashing.fmix32(cand) % jnp.uint32(0x3FFF))
     dport = jnp.full(cand.shape, 4791, jnp.uint32)
-    p = routing.ecmp_paths(
-        jnp.asarray(src, np.uint32)[:, None], jnp.asarray(dst, np.uint32)[:, None],
-        sport, dport, n_paths)
-    hit = np.asarray(p) == np.asarray(target_path, np.int32)[:, None]
-    k = np.where(hit.any(axis=1), hit.argmax(axis=1), 0)
-    return cand[np.arange(cand.shape[0]), k]
+    p = routing.ecmp_paths(src[:, None], dst[:, None], sport, dport, n_paths)
+    hit = p == target_path[:, None]
+    return jnp.where(hit.any(axis=1), jnp.argmax(hit, axis=1), 0)
 
 
 def collective_trace(

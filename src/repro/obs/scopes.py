@@ -19,6 +19,23 @@ Scopes:
 * ``chunk`` — the loop plumbing of ``run_core`` between scan chunks
   (splicing a chunk's outputs into the horizon, the early-exit test,
   recorder rows).
+
+Host spans (``span`` below), on the profiler's clock with the device
+trace:
+
+* ``repro.sweep.prep`` / ``.dispatch`` / ``.fetch`` / ``.unpack`` — a
+  ``sweep.run_batch`` call: sorting, bucketing, window and admission-lane
+  planning, padding, stacking and the host-to-device transfer; the
+  executable call alone; the host waiting for spill / finish / cnp / ff;
+  results and per-sim outputs.  The last three once per (shape bucket,
+  spill retry);
+* ``repro.cosim.epoch`` — one epoch of ``dist.cosim.run_cosim``, holding
+  in order ``repro.cosim.trace`` (fault state, stragglers, the ring
+  schedule and any in-epoch replan), ``repro.cosim.sim`` (the epoch's
+  ``sweep.run_one``), ``repro.cosim.feedback`` (congestion reports or the
+  telemetry channel, the next plan and its versioned application) and
+  ``repro.cosim.record`` (FCT and imbalance statistics, the epoch record,
+  the journal and the flight log).
 """
 from __future__ import annotations
 
@@ -55,6 +72,12 @@ def scope(name: str):
         return scoped
 
     return deco
+
+
+def span(name: str):
+    """``jax.profiler`` host span named ``name``; near-free when no trace is
+    being captured.  Timing only: what runs inside is unchanged."""
+    return jax.profiler.TraceAnnotation(name)
 
 
 def phase_of(op_name: str | None) -> str | None:

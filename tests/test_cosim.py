@@ -53,6 +53,86 @@ def test_traced_capacity_matches_static_and_reuses_program():
     assert not np.array_equal(r_traced.finish, r_dead.finish)
 
 
+def test_static_capacity_keys_the_executable():
+    """Without a capacity operand the topology's capacities are baked into
+    the executable, so two topologies that differ only in capacity must not
+    share one: the second builds its own and sees its own fabric."""
+    from repro.netsim import sweep, topology, workloads
+    from repro.netsim.engine import SimConfig
+
+    healthy = topology.leaf_spine(2, 4, 2, 40e9)
+    cap = np.asarray(healthy.capacity).copy()
+    cap[[1, 2 * 4 + 1]] = 0.0  # spine 1, leaf 0, both directions
+    dead = topology.leaf_spine(2, 4, 2, 40e9, capacity_overrides={1: 0.0, 9: 0.0})
+    np.testing.assert_array_equal(np.asarray(dead.capacity), cap)
+    tr = workloads.poisson_trace(workloads.TraceConfig(
+        workload="fixed:1e6", load=0.5, duration_s=1e-3, n_hosts=healthy.n_hosts,
+        host_bw=40e9, seed=3, hosts_per_leaf=2))
+    cfg = SimConfig(scheme="seqbalance", duration_s=1e-3)
+    r_healthy, _ = sweep.run_one(healthy, cfg, tr)
+    before = sweep.cache_stats()["builds"]
+    r_dead, _ = sweep.run_one(dead, cfg, tr)
+    assert sweep.cache_stats()["builds"] == before + 1
+    r_traced, _ = sweep.run_one(healthy, cfg, tr, capacity=cap)
+    np.testing.assert_array_equal(r_dead.finish, r_traced.finish)
+    assert not np.array_equal(r_dead.finish, r_healthy.finish)
+
+
+# ------------------------------------------------------------ epoch spans
+COSIM_SPANS = ("repro.cosim.trace", "repro.cosim.sim", "repro.cosim.feedback",
+               "repro.cosim.record")
+
+
+def _small_cosim():
+    from repro.dist import cosim
+    from repro.netsim import topology
+
+    topo = topology.three_tier(n_tor=4, n_agg=4, n_core=2, hosts_per_tor=2)
+    return cosim.run_cosim(
+        topo, cosim.ring_hosts(topo, 4), 1e6, scheme="ecmp", epochs=2, n_chunks=2,
+        phi_steps=2, faults=(cosim.kill_spine(topo, 1, epoch=1),))
+
+
+def test_cosim_epoch_spans_in_order_and_results_unchanged(tmp_path):
+    """Each epoch of ``run_cosim`` records ``repro.cosim.epoch`` holding
+    its stages in order, on the profiler's clock; the records are the same
+    to the bit with the profiler on and off."""
+    import glob
+
+    import jax
+
+    from bench.harness import xtrace
+
+    off = _small_cosim()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        on = _small_cosim()
+    finally:
+        jax.profiler.stop_trace()
+    assert glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"), recursive=True)
+    for a, b in zip(off.records, on.records):
+        np.testing.assert_array_equal(a.fct, b.fct)
+        np.testing.assert_array_equal(a.imbalance, b.imbalance)
+        assert (a.quarantined, a.reported_slow, a.completion, a.plan_version) == \
+            (b.quarantined, b.reported_slow, b.completion, b.plan_version)
+    assert [r.quarantined for r in on.records] == [(), ()]
+    assert on.records[1].reported_slow and on.final_plan.inactive == \
+        tuple(p in on.records[1].reported_slow for p in range(len(on.final_plan.inactive)))
+    host = sorted(xtrace.load(str(tmp_path)).host, key=lambda h: h[1])
+    epochs = [(s, s + d) for n, s, d in host if n == "repro.cosim.epoch"]
+    assert len(epochs) == 2
+    stages = [(n, s, s + d) for n, s, d in host if n in COSIM_SPANS]
+    assert [n for n, _, _ in stages] == list(COSIM_SPANS) * 2
+    for k, (e0, e1) in enumerate(epochs):
+        inside = stages[4 * k: 4 * k + 4]
+        assert all(e0 <= s and e <= e1 for _, s, e in inside)
+        assert all(a[2] <= b[1] for a, b in zip(inside, inside[1:]))
+    # the sweep's own spans sit inside each epoch's sim span
+    sims = [(s, e) for n, s, e in stages if n == "repro.cosim.sim"]
+    dispatch = [s for n, s, d in host if n == "repro.sweep.dispatch"]
+    assert len(dispatch) == 2 and all(s0 <= t <= s1 for t, (s0, s1) in zip(dispatch, sims))
+
+
 def test_run_jobs_callable_and_kwargs_spellings():
     from repro.netsim import sweep, topology, workloads
     from repro.netsim.engine import SimConfig
