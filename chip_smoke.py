@@ -6,8 +6,10 @@ fabric sizes, through the entry points a user calls.
     python chip_smoke.py --chips 4   # the two multi-chip paths, on four chips
 
 One chip (paper §V sizes):
-  a  kernel parity: the compiled Pallas ``linkload_cascade_tiered`` against
-     ``kernels/ref.py`` at sim_2tier (N = 4 and N = 1) and three_tier width
+  a  kernel parity: the compiled Pallas ``linkload_cascade_tiered``, over the
+     topology's link-id bands, against ``kernels/ref.py`` and bit for bit
+     against the kernel over all link ids, at sim_2tier and three_tier
+     width (N = 4 and N = 1); per-call times and one-hot rows of both
   b  Fig. 12: sim_2tier, websearch at 80 % load, 10 ms of arrivals, 40 ms
      horizon, five schemes through ``sweep.run_jobs`` (dataplane "auto",
      i.e. Pallas), then seqbalance and ecmp again on the XLA dataplane
@@ -157,8 +159,37 @@ def _sweep_schemes(ph: Phase, topo, trace, schemes, horizon_s, dataplane,
 
 
 # ------------------------------------------------------------------ phases
+def _call_us(fn, *args, reps: int = 100) -> float:
+    """Device-bound microseconds per call: ``reps`` calls queued back to
+    back, timed to the last one's completion."""
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / reps * 1e6
+
+
+def _routes(topo, n: int, n_sub: int, key):
+    """Tiered hop ids of ``n`` random flows, a quarter of them inside one
+    leaf (absent fabric hops), as ``cascade_nic`` receives them."""
+    import jax.numpy as jnp
+
+    ks = jax.random.split(key, 3)
+    hpl = topo.hosts_per_leaf
+    src = jax.random.randint(ks[0], (n,), 0, topo.n_hosts)
+    other = jax.random.randint(ks[1], (n,), 0, topo.n_hosts)
+    dst = jnp.where(jnp.arange(n) % 4 == 0, (src // hpl) * hpl + (src + 1) % hpl, other)
+    path = jax.random.randint(ks[2], (n, n_sub), 0, topo.n_paths)
+    tx, rx = topo.nic_links(src, dst)
+    fab = topo.fabric_links((src // hpl)[:, None], (dst // hpl)[:, None], path)
+    return fab, tx, rx
+
+
 def phase_kernel(clock):
-    """a: compiled Pallas kernel vs its jnp oracle on random inputs."""
+    """a: compiled Pallas kernel, with the topology's link-id bands as the
+    engines pass them, against its jnp oracle and, bit for bit, against
+    the same kernel over all link ids; per-call times of both."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -167,26 +198,28 @@ def phase_kernel(clock):
 
     widths = [("sim_2tier_N4", topology.sim_2tier(), 4),
               ("sim_2tier_N1", topology.sim_2tier(), 1),
-              ("three_tier_N4", topology.three_tier(), 4)]
+              ("three_tier_N4", topology.three_tier(), 4),
+              ("three_tier_N1", topology.three_tier(), 1)]
     n = 4096  # flows per call
     with Phase("a", clock) as ph:
         for name, topo, N in widths:
             L, hf = topo.n_links, topo.n_fabric_hops
-            ks = jax.random.split(jax.random.PRNGKey(L + N), 6)
-            args = (
-                jax.random.randint(ks[0], (n, N, hf), -1, L, jnp.int32),
-                jax.random.randint(ks[1], (n,), 0, L, jnp.int32),
-                jax.random.randint(ks[2], (n,), 0, L, jnp.int32),
-                jax.random.uniform(ks[3], (n, N)) * 100e9 / N,
-                jax.random.uniform(ks[4], (L,)) * 2e6,
+            ks = jax.random.split(jax.random.PRNGKey(L + N), 3)
+            args = _routes(topo, n, N, ks[0]) + (
+                jax.random.uniform(ks[1], (n, N)) * 100e9 / N,
+                jax.random.uniform(ks[2], (L,)) * 2e6,
                 jnp.asarray(topo.capacity[:L]),
                 dataplane.queue_mask_for(topo)[:L],
             )
             kern = jax.jit(lambda *a: ll.linkload_cascade_tiered(
-                *a, n_links=L))
+                *a, n_links=L, bands=topo.bands))
+            full = jax.jit(lambda *a: ll.linkload_cascade_tiered(*a, n_links=L))
             hlo = kern.lower(*args).compile().as_text()
             check("tpu_custom_call" in hlo, f"{name}: no compiled Pallas kernel")
             got = kern(*args)
+            for key, g, f in zip(KERNEL_TOL, got, full(*args)):
+                check(np.array_equal(np.asarray(g), np.asarray(f)),
+                      f"{name} {key}: bands change the kernel's output")
             want = jax.jit(lambda *a: ref.linkload_cascade_tiered_ref(
                 *a[:4], L, 400e3, 1600e3, 0.2, *a[4:], 10e-6))(*args)
             errs = {}
@@ -197,7 +230,10 @@ def phase_kernel(clock):
                 check(not bad.any(), f"{name} {key}: {int(bad.sum())} elements "
                       f"outside {tol}")
                 errs[key] = float(np.max(np.abs(g - w) / (np.abs(w) + 1.0)))
-            ph.fields[name] = dict(n_links=L, flows=n, n_sub=N, max_rel_err=errs)
+            ph.fields[name] = dict(
+                n_links=L, flows=n, n_sub=N, max_rel_err=errs,
+                onehot_rows=ll.onehot_rows(topo.bands, N, hf, L),
+                kernel_us=_call_us(kern, *args), full_width_us=_call_us(full, *args))
 
 
 def phase_fig12(clock):

@@ -481,7 +481,7 @@ def build_compact_sim(topo: Topology, cfg: SimConfig, trace_arrays, W: int, F_pa
                 fab, ca.tx, ca.rx, rc, state.queue, capv, qmask,
                 n_links=nl, kmin=dparams.kmin_bytes, kmax=dparams.kmax_bytes,
                 pmax=dparams.pmax, dt=cfg.dt, qmax_bytes=cfg.qmax_bytes,
-                backend=cfg.dataplane,
+                bands=topo.bands, backend=cfg.dataplane,
             )
             p_sub, p_sub_fabric = dataplane.subflow_mark_probs_nic(
                 fab, ca.tx, ca.rx, p_mark, nl)

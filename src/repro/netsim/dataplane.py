@@ -114,12 +114,15 @@ def cascade_nic(
     pmax: float,
     dt: float,
     qmax_bytes: float,
+    bands: tuple[tuple[int, int], ...] | None = None,
     backend: str = "auto",
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """NIC-tiered hop cascade: same physics as ``cascade`` but exploiting
     that the N sub-flows of a flow share their first (host_tx) and last
     (host_rx) hop — those two segment-sums run over flows (O(W)) instead of
-    sub-flows (O(W*N)), and their scale gathers are per flow.
+    sub-flows (O(W*N)), and their scale gathers are per flow.  ``bands``
+    (``Topology.bands``) narrows each hop's one-hot in the Pallas kernel to
+    the link ids that hop can touch; the XLA backend does not need it.
 
     Returns (arrival[n_links+1], new_queue[n_links+1], p_mark[n_links+1],
     thr[..., N]).  The flat ``cascade`` stays available as the oracle;
@@ -139,8 +142,8 @@ def cascade_nic(
     arrival_l, newq_l, mark_l, thr = ll.linkload_cascade_tiered(
         fab_links.reshape(-1, N, hf), tx_link.reshape(-1), rx_link.reshape(-1),
         rates.reshape(-1, N), queue[:n_links], capacity[:n_links],
-        queue_mask[:n_links], n_links=n_links, kmin=kmin, kmax=kmax,
-        pmax=pmax, dt=dt, qmax_bytes=qmax_bytes,
+        queue_mask[:n_links], n_links=n_links, bands=bands, kmin=kmin,
+        kmax=kmax, pmax=pmax, dt=dt, qmax_bytes=qmax_bytes,
         interpret=(backend == "pallas_interpret"),
     )
     zero = jnp.zeros((1,), jnp.float32)

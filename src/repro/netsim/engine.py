@@ -311,7 +311,7 @@ def build_sim(topo: Topology, cfg: SimConfig, trace: Trace, reorder=None):
                 fab, tx_link, rx_link, rc, state.queue, topo.capacity, qmask,
                 n_links=nl, kmin=dparams.kmin_bytes, kmax=dparams.kmax_bytes,
                 pmax=dparams.pmax, dt=cfg.dt, qmax_bytes=cfg.qmax_bytes,
-                backend=cfg.dataplane,
+                bands=topo.bands, backend=cfg.dataplane,
             )
             p_sub, p_sub_fabric = dataplane.subflow_mark_probs_nic(
                 fab, tx_link, rx_link, p_mark, nl

@@ -54,6 +54,11 @@ class Topology:  # instance can be a jit static argument (fields hold arrays)
     # f(src_leaf, dst_leaf, path) -> i32[..., n_fabric_hops] (-1 = absent)
     fabric_links: Callable
     n_fabric_hops: int
+    # the half-open range of link ids each hop of that view can emit, in
+    # cascade order: host_tx, fabric hop 0..n_fabric_hops-1, host_rx.  The
+    # ranges partition [0, n_links); the Pallas cascade builds each hop's
+    # one-hot over its own range only (kernels/linkload.py)
+    bands: tuple[tuple[int, int], ...]
     # fabric-only view used for congestion metrics / imbalance:
     uplink_ids: np.ndarray  # int32[n_leaf, n_uplinks] — ToR uplink link ids
     base_rtt_s: float
@@ -147,6 +152,7 @@ def leaf_spine(
         nic_links=nic_links,
         fabric_links=fabric_links,
         n_fabric_hops=2,
+        bands=((tx0, tx0 + H), (up0, dn0), (dn0, tx0), (rx0, rx0 + H)),
         uplink_ids=uplink_ids,
         base_rtt_s=base_rtt_s,
         path_link_table=plt,
@@ -231,6 +237,8 @@ def three_tier(
         nic_links=nic_links,
         fabric_links=fabric_links,
         n_fabric_hops=4,
+        bands=((tx0, rx0), (ta0, ac0), (ac0, ca0), (ca0, at0), (at0, tx0),
+               (rx0, rx0 + H)),
         uplink_ids=uplink_ids,
         base_rtt_s=base_rtt_s,
         path_link_table=plt,

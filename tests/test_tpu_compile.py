@@ -48,14 +48,16 @@ def no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-# (fabric, sub-flows per flow): Fig. 12's sim_2tier with SeqBalance's N = 4
-# and the single-path schemes' N = 1, and Fig. 14's 320-host three_tier
-# ``batch`` None is the B = 1 program; 2 is the vmapped program the sweep
-# runs on an accelerator for B > 1 (and, per chip, under its pmap), which
-# adds a grid axis through the Pallas batching rule
+# (fabric, sub-flows per flow): Fig. 12's sim_2tier and Fig. 14's 320-host
+# three_tier, each with SeqBalance's N = 4 and the single-path schemes'
+# N = 1 (the co-sim's steered ECMP), with the topology's link-id bands as
+# the engines pass them.  ``batch`` None is the B = 1 program; 2 is the
+# vmapped program the sweep runs on an accelerator for B > 1 (and, per
+# chip, under its pmap), which adds a grid axis through the Pallas
+# batching rule
 @pytest.mark.parametrize("batch", [None, 2])
 @pytest.mark.parametrize("fabric,n_sub", [("sim_2tier", 4), ("sim_2tier", 1),
-                                          ("three_tier", 4)])
+                                          ("three_tier", 4), ("three_tier", 1)])
 def test_tiered_kernel_compiles_for_v5e(fabric, n_sub, batch, one_chip,
                                         no_persistent_cache):
     topo_ = getattr(topology, fabric)()
@@ -70,7 +72,8 @@ def test_tiered_kernel_compiles_for_v5e(fabric, n_sub, batch, one_chip,
             spec((n,), jnp.int32), spec((n, n_sub), jnp.float32),
             spec((L,), jnp.float32), spec((L,), jnp.float32, False),
             spec((L,), jnp.float32, False))
-    kernel = functools.partial(ll.linkload_cascade_tiered, n_links=L)
+    kernel = functools.partial(ll.linkload_cascade_tiered, n_links=L,
+                               bands=topo_.bands)
     if batch is not None:  # capacity and queue mask are shared, as in sweep
         kernel = jax.vmap(kernel, in_axes=(0, 0, 0, 0, 0, None, None))
     compiled = jax.jit(kernel).lower(*args).compile()
