@@ -134,7 +134,7 @@ FIG12_ARRIVALS_S = 10e-3  # the --full Fig. 12 size; the horizon is 4x
 
 
 def _fig12_trace(seed: int = 1):
-    """benchmarks/paper_benches.py's Fig. 12 websearch-80 % trace."""
+    """The Fig. 12 websearch-80 % trace on sim_2tier."""
     from repro.netsim import topology
 
     topo = topology.sim_2tier()
@@ -266,7 +266,7 @@ def phase_fig14(clock):
 
 
 def phase_cosim(clock):
-    """d: the killed-agg-spine co-sim acceptance row (bench_cosim)."""
+    """d: the killed-agg-spine co-sim acceptance row."""
     from repro.dist import cosim
     from repro.netsim import topology
 
@@ -298,7 +298,7 @@ def phase_oracle(clock):
     topo, arrivals_s = topology.testbed_symmetric(), 2e-3
     trace = _websearch(topo, 0.5, arrivals_s, 3,
                        topo.n_leaf * topo.n_paths * 40e9)
-    # benchmarks/paper_benches.py::_dc40: the testbed's 40G DCQCN settings
+    # the testbed's 40G DCQCN settings
     dc40 = DCQCNParams(kmin_bytes=160e3, kmax_bytes=520e3, r_ai=400e6,
                        min_rate=400e6)
     with Phase("e", clock) as ph:

@@ -1,5 +1,6 @@
 #!/usr/bin/env bash
-# CI smoke: tier-1 test suite + one fast end-to-end paper bench.
+# CI smoke: tier-1 test suite, its forced 8-device reruns, and the co-sim
+# smokes (chaos, telemetry, adaptive dt, observability, flowcells).
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
@@ -19,10 +20,6 @@ XLA_FLAGS="--xla_force_host_platform_device_count=8" \
   python -m pytest -x -q tests/test_collectives.py tests/test_system.py \
   tests/test_dist_extra.py -k "not equals_psum and not across_mesh_sizes"
 
-# bench_fig10 fast mode: exercises trace generation, the sweep runner, the
-# compact engine, and the metrics layer end to end in under a minute.
-python -m benchmarks.run --only fig10 --json /tmp/BENCH_smoke.json
-
 # 2-epoch co-sim smoke on the forced 8-device platform: the training-side
 # plan -> fluid-sim -> quarantine -> plan loop (dist.cosim via launch.train
 # --cosim-epochs), healthy fabric — just the loop plumbing, the sharded
@@ -30,26 +27,6 @@ python -m benchmarks.run --only fig10 --json /tmp/BENCH_smoke.json
 XLA_FLAGS="--xla_force_host_platform_device_count=8" \
   python -m repro.launch.train --cosim-epochs 2 --cosim-kill-spine -1 \
   --cosim-only
-
-# perf regression gate: rerun the fig12 fast sweep (compact + dense oracle)
-# and fail if the compact per-step cost regressed >30% vs the committed
-# baseline, if the compact-vs-dense stat divergence exceeds 0.01%, or if
-# the sweep spilled.  Skip with REPRO_CI_SKIP_BENCH_GATE=1 (e.g. on a
-# machine unrelated to the committed baseline's).
-if [ -z "${REPRO_CI_SKIP_BENCH_GATE:-}" ]; then
-  python -m benchmarks.run --only netsim_speedup --json /tmp/BENCH_gate.json
-  python scripts/check_bench.py /tmp/BENCH_gate.json BENCH_netsim.json
-fi
-
-# co-sim convergence gate: rerun the fast killed-spine scenarios and fail
-# if any scenario's convergence-epoch count regressed by more than 1 vs
-# the committed record, if one stopped converging, or if epochs after the
-# first rebuilt sweep executables (the traced-capacity reuse contract).
-if [ -z "${REPRO_CI_SKIP_BENCH_GATE:-}" ]; then
-  python -m benchmarks.run --only cosim --json /tmp/BENCH_cosim.json
-  python scripts/check_bench.py /tmp/BENCH_cosim.json BENCH_netsim.json \
-    --cosim
-fi
 
 # chaos smoke on the forced 8-device platform: a seeded 3-fault random
 # campaign (flap / lossy / straggler mix) runs end to end through the
@@ -79,15 +56,6 @@ for h in hists:
     print(f"chaos smoke: {h.scheme} reconverged at epoch {conv}, "
           f"0 crashed cells")
 EOF
-
-# chaos-campaign gate: rerun the fast campaign bench and fail on crashed
-# (salvaged) cells, lost reconvergence, or a >30% worst censored-p99
-# regression vs the committed record.
-if [ -z "${REPRO_CI_SKIP_BENCH_GATE:-}" ]; then
-  python -m benchmarks.run --only faults --json /tmp/BENCH_faults.json
-  python scripts/check_bench.py /tmp/BENCH_faults.json BENCH_netsim.json \
-    --faults
-fi
 
 # degraded-telemetry smoke on the forced 8-device platform: the same
 # killed-spine scenario with its congestion reports pushed through a
@@ -123,16 +91,6 @@ assert safe, "blackout never tripped safe mode"
 assert not hb.records[-1].safe_mode, "never recovered from safe mode"
 print(f"telemetry smoke: blackout safe-mode epochs {safe}, recovered")
 EOF2
-
-# degraded-telemetry gate: rerun the telemetry bench and fail on a broken
-# perfect-channel bit-identity, unbounded lossy/delayed reconvergence,
-# non-monotone plan versions, a blackout that misses safe mode, or a >1
-# convergence-epoch regression vs the committed record.
-if [ -z "${REPRO_CI_SKIP_BENCH_GATE:-}" ]; then
-  python -m benchmarks.run --only telemetry --json /tmp/BENCH_telemetry.json
-  python scripts/check_bench.py /tmp/BENCH_telemetry.json BENCH_netsim.json \
-    --telemetry
-fi
 
 # adaptive-dt co-sim smoke on the forced 8-device platform: the killed-
 # spine scenario with the event-driven adaptive engine enabled must
@@ -177,16 +135,6 @@ print(f"adaptive smoke: cosim reconverged at epoch "
       f"collective ff {res_a.ff_steps} steps, finish times identical")
 EOF3
 
-# adaptive-dt gate: rerun the adaptive bench and fail on adaptive-vs-fixed
-# stat divergence, a speedup below the committed floors (collective >= 2x,
-# fig12 parity), a collective run that never fast-forwards, or any
-# executable rebuild after the first adaptive dispatch.
-if [ -z "${REPRO_CI_SKIP_BENCH_GATE:-}" ]; then
-  python -m benchmarks.run --only adaptive --json /tmp/BENCH_adaptive.json
-  python scripts/check_bench.py /tmp/BENCH_adaptive.json BENCH_netsim.json \
-    --adaptive
-fi
-
 # observability smoke on the forced 8-device platform: a 2-epoch recorded
 # co-sim must produce a schema-v2 flight log covering both epochs (with
 # the in-sim ring-buffer drain on each), export to a perfetto-loadable
@@ -224,15 +172,6 @@ print(f"obs smoke: 2-epoch flight log, {len(trace['traceEvents'])} trace "
       f"events, matrix {m['matrix'].shape}, driver bit-identical, 0 rebuilds")
 EOF4
 
-# observability gate: rerun the obs bench and fail if warm recording
-# overhead exceeds the committed floor (5%), if the recorder rebuilt an
-# executable after its first dispatch, or if the killed-agg-spine flight
-# log missed an epoch / its in-sim drain.
-if [ -z "${REPRO_CI_SKIP_BENCH_GATE:-}" ]; then
-  python -m benchmarks.run --only obs --json /tmp/BENCH_obs.json
-  python scripts/check_bench.py /tmp/BENCH_obs.json BENCH_netsim.json --obs
-fi
-
 # flowcell smoke on the forced 8-device platform: a flowcell-split plan
 # (chunks sprayed over every active path) plus a live go-back-N reorder
 # budget must run through the co-sim loop with ZERO executable rebuilds
@@ -259,15 +198,3 @@ assert [r.fct_p99_s for r in h0.records] == [r.fct_p99_s for r in h1.records]
 print(f"flowcell smoke: 3-epoch co-sim with flowcells=4 / budget=16 MTU, "
       f"0 rebuilds after epoch 0, degenerate knobs bit-identical")
 EOF5
-
-# flowcell gate: rerun the flowcell bench and fail if spraying stops
-# beating SeqBalance in the cost-free arm, stops losing at the strict
-# go-back-N budget on the symmetric fabric (the paper's no-reordering
-# motivation, quantified), if the hetero-fabric grid goes missing, if the
-# co-sim rebuilt an executable after epoch 0, or if the degenerate arms'
-# stat diff is not EXACTLY zero.
-if [ -z "${REPRO_CI_SKIP_BENCH_GATE:-}" ]; then
-  python -m benchmarks.run --only flowcell --json /tmp/BENCH_flowcell.json
-  python scripts/check_bench.py /tmp/BENCH_flowcell.json BENCH_netsim.json \
-    --flowcell
-fi

@@ -189,51 +189,6 @@ class CosimHistory:
                 return r.epoch
         return None
 
-    def fct_cdf(self, epochs: list[int] | None = None, points: int = 50):
-        from repro.netsim import metrics
-
-        rs = self.records if epochs is None else \
-            [r for r in self.records if r.epoch in epochs]
-        return metrics.cdf(np.concatenate([r.fct for r in rs]), points)
-
-    def imbalance_cdf(self, epochs: list[int] | None = None,
-                      points: int = 50):
-        from repro.netsim import metrics
-
-        rs = self.records if epochs is None else \
-            [r for r in self.records if r.epoch in epochs]
-        samples = np.concatenate([r.imbalance for r in rs]) if any(
-            r.imbalance.size for r in rs) else np.zeros(1)
-        return metrics.cdf(samples, points)
-
-    def as_record(self) -> dict:
-        """JSON-able per-epoch curves for BENCH_netsim.json."""
-        rs = self.records
-        return dict(
-            scheme=self.scheme,
-            phi_steps=self.phi_steps,
-            epochs=self.epochs,
-            duration_ms=round(self.duration_s * 1e3, 3),
-            p50_us=[round(r.fct_p50_s * 1e6, 2) for r in rs],
-            p99_us=[round(r.fct_p99_s * 1e6, 2) for r in rs],
-            completion=[round(r.completion, 4) for r in rs],
-            imbalance_mean=[round(r.imbalance_mean, 4) for r in rs],
-            plan_churn=[r.plan_churn for r in rs],
-            n_quarantined=[len(r.quarantined) for r in rs],
-            spill_steps=[r.spill_steps for r in rs],
-            new_builds=[r.new_builds for r in rs],
-            replan_round=[r.replan_round for r in rs],
-            straggler_scale=[round(r.straggler_scale, 3) for r in rs],
-            n_straggler_quarantined=[len(r.straggler_quarantined) for r in rs],
-            safe_mode=[bool(r.safe_mode) for r in rs],
-            plan_version=[r.plan_version for r in rs],
-            reports_sent=[r.reports_sent for r in rs],
-            reports_delivered=[r.reports_delivered for r in rs],
-            reports_admitted=[r.reports_admitted for r in rs],
-            reports_stale=[r.reports_stale for r in rs],
-            reports_duplicate=[r.reports_duplicate for r in rs],
-        )
-
     def summary_lines(self) -> list[str]:
         return [
             f"epoch {r.epoch:2d} p99 {r.fct_p99_s * 1e6:8.1f}us "

@@ -10,8 +10,7 @@ Grid: (B * H, Sq / block_q).  GQA maps query head h to kv head h // G in
 the BlockSpec index maps — no materialized head repetition.
 
 Validated in interpret mode on CPU against kernels/ref.py over a
-shape/dtype sweep (tests/test_kernels.py); ``ops.flash_attention`` is the
-jit'd entry point the model layer can switch to on TPU.
+shape/dtype sweep (tests/test_kernels.py); no model path calls it.
 """
 from __future__ import annotations
 

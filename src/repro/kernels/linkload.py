@@ -35,7 +35,7 @@ VMEM: the one-hot and its iota are ``rows * block_n * 4`` bytes each; at
 each at ``block_n=256`` (2.2 MB over all 2176 padded links without bands),
 inside the default scoped-VMEM limit.
 
-Oracles: kernels/ref.py::linkload_ref / linkload_cascade_tiered_ref.
+Oracle: kernels/ref.py::linkload_cascade_tiered_ref.
 """
 from __future__ import annotations
 
@@ -85,74 +85,6 @@ def _lanes(x: int) -> int:
 
 def _pad_links(n_links: int) -> int:
     return _lanes(n_links + 1)
-
-
-def _linkload_kernel(
-    lid_ref, rate_ref, queue_ref, cap_ref, load_ref, newq_ref, mark_ref,
-    *, n_links_padded, kmin, kmax, pmax, dt,
-):
-    ti = pl.program_id(0)
-
-    @pl.when(ti == 0)
-    def _init():
-        load_ref[...] = jnp.zeros_like(load_ref)
-
-    lids = lid_ref[...]  # [hops, block_n] i32 (-1 = none: matches no row)
-    rates = rate_ref[...]  # [1, block_n]
-    for h in range(lids.shape[0]):
-        load_ref[...] += _scatter(rates, _onehot(lids[h:h + 1], n_links_padded))
-
-    @pl.when(ti == pl.num_programs(0) - 1)
-    def _finalize():
-        load = load_ref[...]
-        newq = jnp.clip(queue_ref[...] + (load - cap_ref[...]) * dt / 8.0,
-                        0.0, 8e6)
-        newq_ref[...] = newq
-        mark_ref[...] = _red_mark(newq, kmin, kmax, pmax)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("n_links", "kmin", "kmax", "pmax", "dt", "block_n", "interpret")
-)
-def linkload(
-    link_ids: jax.Array,  # i32[n, hops]
-    rates: jax.Array,  # f32[n]
-    queue: jax.Array,  # f32[n_links]
-    capacity: jax.Array,  # f32[n_links]
-    *,
-    n_links: int,
-    kmin: float = 400e3,
-    kmax: float = 1600e3,
-    pmax: float = 0.2,
-    dt: float = 10e-6,
-    block_n: int = 256,
-    interpret: bool = False,
-):
-    """(load, new_queue, mark) of the ToR/spine step; oracle ref.linkload_ref."""
-    n, hops = link_ids.shape
-    pad_n = (-n) % block_n
-    lid_t = jnp.pad(link_ids.astype(jnp.int32), ((0, pad_n), (0, 0)),
-                    constant_values=-1).T  # [hops, n_pad]
-    rates_r = jnp.pad(rates.astype(jnp.float32), (0, pad_n))[None, :]
-    L_pad = _pad_links(n_links)
-    row = pl.BlockSpec((1, L_pad), lambda t: (0, 0))
-    load, newq, mark = pl.pallas_call(
-        functools.partial(
-            _linkload_kernel,
-            n_links_padded=L_pad, kmin=kmin, kmax=kmax, pmax=pmax, dt=dt,
-        ),
-        grid=((n + pad_n) // block_n,),
-        in_specs=[
-            pl.BlockSpec((hops, block_n), lambda t: (0, t)),
-            pl.BlockSpec((1, block_n), lambda t: (0, t)),
-            row, row,
-        ],
-        out_specs=[row, row, row],
-        out_shape=[jax.ShapeDtypeStruct((1, L_pad), jnp.float32)] * 3,
-        interpret=interpret,
-        name="linkload",
-    )(lid_t, rates_r, _row(queue, L_pad), _row(capacity[:n_links], L_pad, 1e30))
-    return load[0, :n_links], newq[0, :n_links], mark[0, :n_links]
 
 
 def _band_width(band: tuple[int, int]) -> int:
@@ -377,41 +309,3 @@ def linkload_cascade_tiered(
     )(fab_t, tx_r, rx_r, rates_t, _row(queue, L_pad),
       _row(capacity[:n_links], L_pad, 1e30), _row(queue_mask[:n_links], L_pad))
     return arrival[0, :n_links], newq[0, :n_links], mark[0, :n_links], thr.T[:n]
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "n_links", "kmin", "kmax", "pmax", "dt", "qmax_bytes", "block_n", "interpret"
-    ),
-)
-def linkload_cascade(
-    link_ids: jax.Array,  # i32[n, hops]  (-1 = no hop)
-    rates: jax.Array,  # f32[n]
-    queue: jax.Array,  # f32[n_links]
-    capacity: jax.Array,  # f32[n_links]
-    queue_mask: jax.Array,  # f32[n_links]
-    *,
-    n_links: int,
-    kmin: float = 400e3,
-    kmax: float = 1600e3,
-    pmax: float = 0.2,
-    dt: float = 10e-6,
-    qmax_bytes: float = 8e6,
-    block_n: int = 256,
-    interpret: bool = False,
-):
-    """Flat fused dataplane step: (arrival, new_queue, mark, thr) — every hop
-    per flow.  The same kernel as ``linkload_cascade_tiered`` with one
-    sub-flow per flow: hop 0 is its tx pass, the last hop its rx pass and
-    the hops between its fabric passes (one absent hop when there are
-    none).  Oracle: kernels/ref.py::linkload_cascade_ref."""
-    assert link_ids.shape[1] >= 2, "a flat route has a tx and an rx hop"
-    lid = link_ids.astype(jnp.int32)
-    fab = lid[:, 1:-1] if lid.shape[1] > 2 else jnp.full((lid.shape[0], 1), -1, jnp.int32)
-    arrival, newq, mark, thr = linkload_cascade_tiered(
-        fab[:, None, :], lid[:, 0], lid[:, -1], rates[:, None], queue,
-        capacity, queue_mask, n_links=n_links, kmin=kmin, kmax=kmax, pmax=pmax,
-        dt=dt, qmax_bytes=qmax_bytes, block_n=block_n, interpret=interpret,
-    )
-    return arrival, newq, mark, thr[:, 0]

@@ -1,4 +1,8 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose ground truth)."""
+"""Pure-jnp oracles for every Pallas kernel (the allclose ground truth).
+
+``linkload_cascade_tiered_ref`` is also the dataplane's path off the TPU
+(``netsim/dataplane.cascade_nic``, backend ``xla``); ``linkload_cascade_ref``
+is the flat cascade, the oracle the tiered layout is checked against."""
 from __future__ import annotations
 
 import jax
@@ -113,25 +117,3 @@ def linkload_cascade_tiered_ref(
     ramp = (new_queue - kmin) / (kmax - kmin)
     mark = jnp.where(new_queue < kmin, 0.0, jnp.where(new_queue > kmax, 1.0, ramp * pmax))
     return arrival, new_queue, mark.astype(jnp.float32), thr
-
-
-def linkload_ref(
-    link_ids: jax.Array,  # i32[n, hops]  (-1 = no link)
-    rates: jax.Array,  # f32[n]
-    n_links: int,
-    kmin: float,
-    kmax: float,
-    pmax: float,
-    queue: jax.Array,  # f32[n_links] current queue bytes
-    capacity: jax.Array,  # f32[n_links]
-    dt: float,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """(load, new_queue, mark_prob) — the ToR/spine dataplane step."""
-    hops = link_ids.shape[1]
-    contrib = jnp.broadcast_to(rates[:, None], link_ids.shape).reshape(-1)
-    lid = jnp.where(link_ids >= 0, link_ids, n_links).reshape(-1)
-    load = jax.ops.segment_sum(contrib, lid, num_segments=n_links + 1)[:n_links]
-    new_queue = jnp.clip(queue + (load - capacity) * dt / 8.0, 0.0, 8e6)
-    ramp = (new_queue - kmin) / (kmax - kmin)
-    mark = jnp.where(new_queue < kmin, 0.0, jnp.where(new_queue > kmax, 1.0, ramp * pmax))
-    return load, new_queue, mark.astype(jnp.float32)

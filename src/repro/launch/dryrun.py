@@ -19,8 +19,8 @@ multi-pod mesh, compiles it, and records:
   * collective bytes (parsed from the partitioned HLO: all-gather,
     all-reduce, reduce-scatter, all-to-all, collective-permute)
 
-Results append to benchmarks/artifacts/dryrun_<mesh>.json, which
-benchmarks/roofline.py turns into EXPERIMENTS.md §Roofline.
+Results go to ``<--out>/dryrun_<mesh>_<grad-sync>.json`` (default
+``build/dryrun``, which git ignores).
 
 Usage:
   python -m repro.launch.dryrun --arch gemma2-2b --shape train_4k --mesh single
@@ -314,7 +314,7 @@ def main():
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--grad-sync", default="xla", choices=["xla", "seqbalance"])
     ap.add_argument("--remat", default="dots", choices=["none", "dots", "full"])
-    ap.add_argument("--out", default="benchmarks/artifacts")
+    ap.add_argument("--out", default="build/dryrun")
     args = ap.parse_args()
 
     meshes = []

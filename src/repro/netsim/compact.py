@@ -31,9 +31,8 @@ and the queues have drained, the remaining steps are exact no-ops), and
 ``cfg.uplink_sample_every`` folds the imbalance window-averaging into the
 scan so sweeps stop materializing the full ``[T, L, S]`` uplink trace.
 
-The dense engine stays available as the correctness oracle
-(``benchmarks/common.run_sim(dense=True)``); equivalence is asserted in
-tests/test_netsim_compact.py and recorded per-sweep in BENCH_netsim.json.
+The dense engine (``engine.simulate``) stays available as the correctness
+oracle; equivalence is asserted in tests/test_netsim_compact.py.
 """
 from __future__ import annotations
 
@@ -149,9 +148,7 @@ def plan_single_window(topo: Topology, cfg: SimConfig, arrays: tuple,
                        F_pad: int) -> tuple[int, int]:
     """(W, A) for a single sorted trace: the concurrency-bound window
     (128-bucketed, floored at min(128, F_pad)) and the exact-peak admission
-    lane (32-bucketed).  Shared by ``simulate_compact`` and
-    ``profile.quiescence_profile``, so the replay runs the production
-    shapes."""
+    lane (32-bucketed), as ``simulate_compact`` sizes a single sim."""
     line_rate = float(np.asarray(line_rate_of(topo)))
     bound = max_concurrency_bound(arrays[0], arrays[1], arrays[5], line_rate)
     W = int(min(((bound + 127) // 128) * 128, F_pad))
@@ -731,8 +728,7 @@ def event_grid(cfg: SimConfig, n_steps: int, arrivals=None, valid=None,
     macro-steps are whole scan chunks (K a multiple of the sample window,
     via ``plan_chunks``), the quiescence predicate refuses any span
     containing an arrival or a capacity edge, and finishes/ECN crossings
-    are excluded dynamically.  Exposed for planning and for the
-    ``--profile`` quiescence-occupancy report."""
+    are excluded dynamically.  Exposed for planning."""
     edges = [np.array([0, n_steps], np.int64)]
     if arrivals is not None:
         a = np.asarray(arrivals, np.float64)

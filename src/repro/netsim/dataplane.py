@@ -17,18 +17,18 @@ share bit-identical math:
 Both engines route through the NIC-TIERED form (``cascade_nic``): the N
 sub-flows of a flow always share their first (host_tx) and last (host_rx)
 hop, so those two hops pre-reduce over N and cost O(W) instead of O(W*N);
-only the fabric hops stay per sub-flow.  The flat ``cascade`` (identical
-physics, no pre-reduction) is kept as the oracle — tiered vs flat agree to
-float round-off (summation grouping differs), checked in
-tests/test_netsim_compact.py and the hypothesis property suite.
+only the fabric hops stay per sub-flow.  The flat form (identical physics,
+no pre-reduction) is ``kernels/ref.py::linkload_cascade_ref``, the oracle
+the tiered form is checked against in tests/test_netsim_compact.py and the
+hypothesis property suite; they agree to float round-off (summation
+grouping differs).
 
-Backends (both layouts)
-  * ``xla``    — ``jax.ops.segment_sum`` per hop (the original engine loop;
-    also the correctness oracle, mirrored in ``kernels/ref.py``).
-  * ``pallas`` — one fused ``kernels/linkload.py::linkload_cascade`` /
-    ``linkload_cascade_tiered`` call: the scatter-adds become one-hot
-    matmuls on the MXU, the cascade walks hops in the grid, and queue/mark
-    fuse into the final grid step.
+Backends of ``cascade_nic``
+  * ``xla``    — ``kernels/ref.py::linkload_cascade_tiered_ref``, a
+    ``jax.ops.segment_sum`` per hop; also the kernel's oracle.
+  * ``pallas`` — one fused ``kernels/linkload.py::linkload_cascade_tiered``
+    call: the scatter-adds become one-hot matmuls on the MXU, the cascade
+    walks hops in the grid, and queue/mark fuse into the final grid step.
   * ``pallas_interpret`` — the same kernel interpreted on CPU (tests).
   * ``auto``   — pallas (compiled) on TPU, xla everywhere else; the
     interpreter is never picked implicitly.
@@ -44,6 +44,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import linkload as ll, ref
 from repro.netsim.topology import Topology
 
 _BACKENDS = ("auto", "xla", "pallas", "pallas_interpret")
@@ -54,49 +55,6 @@ def resolve_backend(backend: str) -> str:
     if backend == "auto":
         return "pallas" if jax.default_backend() == "tpu" else "xla"
     return backend
-
-
-def cascade(
-    links: jax.Array,  # i32[..., H] link ids, -1 = hop absent
-    rates: jax.Array,  # f32[...] offered rate per sub-flow (bps)
-    queue: jax.Array,  # f32[n_links + 1] current queue bytes (sentinel last)
-    capacity: jax.Array,  # f32[n_links + 1] bps (sentinel = 1e30)
-    queue_mask: jax.Array,  # f32[n_links + 1] 0 on queueless links (host_tx)
-    *,
-    n_links: int,
-    kmin: float,
-    kmax: float,
-    pmax: float,
-    dt: float,
-    qmax_bytes: float,
-    backend: str = "auto",
-) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Returns (arrival[n_links+1], new_queue[n_links+1], p_mark[n_links+1],
-    thr[...]) — thr is the delivered rate after all hop scales."""
-    backend = resolve_backend(backend)
-    if backend == "xla":
-        return _cascade_xla(
-            links, rates, queue, capacity, queue_mask,
-            n_links=n_links, kmin=kmin, kmax=kmax, pmax=pmax, dt=dt,
-            qmax_bytes=qmax_bytes,
-        )
-    from repro.kernels import linkload as ll
-
-    shape = rates.shape
-    hops = links.shape[-1]
-    flat_links = links.reshape(-1, hops)
-    flat_rates = rates.reshape(-1)
-    arrival_l, newq_l, mark_l, thr = ll.linkload_cascade(
-        flat_links, flat_rates, queue[:n_links], capacity[:n_links],
-        queue_mask[:n_links], n_links=n_links, kmin=kmin, kmax=kmax,
-        pmax=pmax, dt=dt, qmax_bytes=qmax_bytes,
-        interpret=(backend == "pallas_interpret"),
-    )
-    zero = jnp.zeros((1,), jnp.float32)
-    arrival = jnp.concatenate([arrival_l, zero])
-    new_queue = jnp.concatenate([newq_l, zero])
-    p_mark = jnp.concatenate([mark_l, zero])
-    return arrival, new_queue, p_mark, thr.reshape(shape)
 
 
 def cascade_nic(
@@ -117,111 +75,38 @@ def cascade_nic(
     bands: tuple[tuple[int, int], ...] | None = None,
     backend: str = "auto",
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """NIC-tiered hop cascade: same physics as ``cascade`` but exploiting
+    """NIC-tiered hop cascade: the flat cascade's physics, exploiting
     that the N sub-flows of a flow share their first (host_tx) and last
     (host_rx) hop — those two segment-sums run over flows (O(W)) instead of
     sub-flows (O(W*N)), and their scale gathers are per flow.  ``bands``
     (``Topology.bands``) narrows each hop's one-hot in the Pallas kernel to
     the link ids that hop can touch; the XLA backend does not need it.
 
-    Returns (arrival[n_links+1], new_queue[n_links+1], p_mark[n_links+1],
-    thr[..., N]).  The flat ``cascade`` stays available as the oracle;
-    tiered vs flat agree to float round-off (summation order differs)."""
-    backend = resolve_backend(backend)
-    if backend == "xla":
-        return _cascade_nic_xla(
-            fab_links, tx_link, rx_link, rates, queue, capacity, queue_mask,
-            n_links=n_links, kmin=kmin, kmax=kmax, pmax=pmax, dt=dt,
-            qmax_bytes=qmax_bytes,
-        )
-    from repro.kernels import linkload as ll
-
+    Both backends take the real links ``[:n_links]`` and append a zero
+    sentinel slot to the per-link outputs.  Returns (arrival[n_links+1],
+    new_queue[n_links+1], p_mark[n_links+1], thr[..., N])."""
     shape = rates.shape  # [..., N]
     N = shape[-1]
     hf = fab_links.shape[-1]
-    arrival_l, newq_l, mark_l, thr = ll.linkload_cascade_tiered(
-        fab_links.reshape(-1, N, hf), tx_link.reshape(-1), rx_link.reshape(-1),
-        rates.reshape(-1, N), queue[:n_links], capacity[:n_links],
-        queue_mask[:n_links], n_links=n_links, bands=bands, kmin=kmin,
-        kmax=kmax, pmax=pmax, dt=dt, qmax_bytes=qmax_bytes,
-        interpret=(backend == "pallas_interpret"),
-    )
+    args = (fab_links.reshape(-1, N, hf), tx_link.reshape(-1),
+            rx_link.reshape(-1), rates.reshape(-1, N))
+    backend = resolve_backend(backend)
+    if backend == "xla":
+        arrival_l, newq_l, mark_l, thr = ref.linkload_cascade_tiered_ref(
+            *args, n_links, kmin, kmax, pmax, queue[:n_links],
+            capacity[:n_links], queue_mask[:n_links], dt, qmax_bytes)
+    else:
+        arrival_l, newq_l, mark_l, thr = ll.linkload_cascade_tiered(
+            *args, queue[:n_links], capacity[:n_links], queue_mask[:n_links],
+            n_links=n_links, bands=bands, kmin=kmin, kmax=kmax, pmax=pmax,
+            dt=dt, qmax_bytes=qmax_bytes,
+            interpret=(backend == "pallas_interpret"),
+        )
     zero = jnp.zeros((1,), jnp.float32)
     arrival = jnp.concatenate([arrival_l, zero])
     new_queue = jnp.concatenate([newq_l, zero])
     p_mark = jnp.concatenate([mark_l, zero])
     return arrival, new_queue, p_mark, thr.reshape(shape)
-
-
-def _cascade_nic_xla(fab_links, tx_link, rx_link, rates, queue, capacity,
-                     queue_mask, *, n_links, kmin, kmax, pmax, dt, qmax_bytes):
-    nl = n_links
-    N = rates.shape[-1]
-    hf = fab_links.shape[-1]
-    tx = tx_link.reshape(-1)
-    rx = rx_link.reshape(-1)
-    r = rates.reshape(-1, N)  # [W, N]
-    lid = jnp.where(fab_links >= 0, fab_links, nl)
-
-    # hop 0: host NIC serialization — pre-reduced over the N sub-flows
-    tx_load = jax.ops.segment_sum(r.sum(-1), tx, num_segments=nl + 1)
-    arrival = tx_load.at[nl].set(0.0)
-    scale = jnp.minimum(1.0, capacity / jnp.maximum(tx_load, 1.0))
-    r = r * scale[tx][:, None]
-
-    # fabric hops: per sub-flow (paths differ), flat segment-sum over W*N
-    rf = r.reshape(-1)
-    lidf = lid.reshape(-1, hf)
-    for h in range(hf):
-        lh = lidf[:, h]
-        load_h = jax.ops.segment_sum(rf, lh, num_segments=nl + 1)
-        arrival = arrival + load_h.at[nl].set(0.0)
-        scale_h = jnp.minimum(1.0, capacity / jnp.maximum(load_h, 1.0))
-        rf = rf * scale_h[lh]
-    r = rf.reshape(-1, N)
-
-    # last hop: receiver NIC — pre-reduced again
-    rx_load = jax.ops.segment_sum(r.sum(-1), rx, num_segments=nl + 1)
-    arrival = arrival + rx_load.at[nl].set(0.0)
-    scale = jnp.minimum(1.0, capacity / jnp.maximum(rx_load, 1.0))
-    thr = r * scale[rx][:, None]
-
-    new_queue = jnp.clip(
-        queue + (arrival - capacity) * dt / 8.0, 0.0, qmax_bytes
-    ) * queue_mask
-    ramp = (new_queue - kmin) / (kmax - kmin)
-    p_mark = jnp.where(
-        new_queue < kmin, 0.0, jnp.where(new_queue > kmax, 1.0, ramp * pmax)
-    ).astype(jnp.float32)
-    p_mark = p_mark.at[nl].set(0.0)
-    return arrival, new_queue, p_mark, thr.reshape(rates.shape)
-
-
-def _cascade_xla(links, rates, queue, capacity, queue_mask, *, n_links,
-                 kmin, kmax, pmax, dt, qmax_bytes):
-    nl = n_links
-    hops = links.shape[-1]
-    flat_links = links.reshape(-1, hops)
-    lid = jnp.where(flat_links >= 0, flat_links, nl)
-    r = rates.reshape(-1)
-    arrival = jnp.zeros((nl + 1,), jnp.float32)
-    for h in range(hops):
-        lh = lid[:, h]
-        load_h = jax.ops.segment_sum(r, lh, num_segments=nl + 1)
-        arrival = arrival + load_h.at[nl].set(0.0)
-        # per-LINK scale, then one gather — the sentinel link has cap 1e30
-        # so absent hops land on scale exactly 1.0 (no where() needed)
-        scale_h = jnp.minimum(1.0, capacity / jnp.maximum(load_h, 1.0))
-        r = r * scale_h[lh]
-    new_queue = jnp.clip(
-        queue + (arrival - capacity) * dt / 8.0, 0.0, qmax_bytes
-    ) * queue_mask
-    ramp = (new_queue - kmin) / (kmax - kmin)
-    p_mark = jnp.where(
-        new_queue < kmin, 0.0, jnp.where(new_queue > kmax, 1.0, ramp * pmax)
-    ).astype(jnp.float32)
-    p_mark = p_mark.at[nl].set(0.0)
-    return arrival, new_queue, p_mark, r.reshape(rates.shape)
 
 
 def subflow_mark_probs(
@@ -360,7 +245,7 @@ def integrate_queue(
     n_links: int,
 ) -> tuple[jax.Array, jax.Array]:
     """Queue integration + RED/ECN marks for dataplanes that compute their
-    own arrival vector (DRILL's spray).  cascade() fuses the same update."""
+    own arrival vector (DRILL's spray).  cascade_nic() fuses the same update."""
     from repro.netsim import dcqcn as dcqcn_mod
 
     new_queue = jnp.clip(

@@ -286,8 +286,8 @@ def build_sim(topo: Topology, cfg: SimConfig, trace: Trace, reorder=None):
         # then fabric: a hop's arrivals are the UPSTREAM-scaled rates, so a
         # host can never inject more than its NIC line rate into the fabric).
         # The pipeline lives in netsim/dataplane.py, shared with the
-        # active-window engine and the linkload_cascade Pallas kernels; the
-        # NIC-tiered form pre-reduces the N sub-flows sharing a host NIC.
+        # active-window engine and the linkload_cascade_tiered Pallas kernel;
+        # the NIC-tiered form pre-reduces the N sub-flows sharing a host NIC.
         if cfg.scheme == "drill":
             arrival, thr, w, pq = dataplane.drill_spray(
                 topo, state.queue, rc[:, 0], src, dst, src_leaf, dst_leaf,

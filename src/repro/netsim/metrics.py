@@ -74,15 +74,6 @@ def fct_samples(state, trace: Trace,
     return (f - arrivals[valid]).astype(np.float64), completion
 
 
-def cdf(samples: np.ndarray, points: int = 50) -> tuple[np.ndarray, np.ndarray]:
-    xs = np.sort(samples)
-    ys = np.arange(1, len(xs) + 1) / len(xs)
-    if len(xs) > points:
-        idx = np.linspace(0, len(xs) - 1, points).astype(int)
-        xs, ys = xs[idx], ys[idx]
-    return xs, ys
-
-
 def congestion_packet_bandwidth(state: SimState, duration_s: float,
                                 pkt_bytes: float = 64.0) -> float:
     """Table II: bps consumed by mirrored Congestion Packets."""

@@ -29,8 +29,8 @@ per sim.  This runner instead:
     XLA compiles are disk hits.
 
 ``run_batch`` is the workhorse; ``run_one`` is the single-trace
-convenience wrapper used by benchmarks/common.run_sim.  ``run_jobs``
-worker count comes from REPRO_SWEEP_WORKERS (default: capped cpu count).
+convenience wrapper (the co-sim's entry).  ``run_jobs`` worker count comes
+from REPRO_SWEEP_WORKERS (default: capped cpu count).
 """
 from __future__ import annotations
 
@@ -86,7 +86,7 @@ def obs_stats() -> dict:
 
 
 def clear_cache() -> None:
-    """Drop compiled executables (benchmarks call this to time cold runs)."""
+    """Drop compiled executables, so the next dispatch builds cold."""
     _JIT_CACHE.clear()
     _ABSTRACT.clear()
     _CACHE_STATS["builds"] = 0
@@ -175,7 +175,7 @@ def _compiled(topo: Topology, cfg: SimConfig, W: int, F_pad: int, A: int,
     keys the executable too: the ring buffer's shapes are a pure function
     of the spec, so recording costs exactly one extra program per (shape
     bucket, spec) and never a rebuild across epochs — the contract
-    ``check_bench.py --obs`` gates."""
+    tests/test_obs.py asserts."""
     key = (_topo_key(topo, any(ops_sig)), cfg, W, F_pad, A, n_steps, batch,
            ops_sig, cap_seg_steps, cap_rows, record)
     fn = _JIT_CACHE.get(key)

@@ -14,8 +14,8 @@ Two builders, matching the paper's evaluation setups:
 Links live in one flat capacity vector; every (sub-)flow touches at most
 ``MAX_HOPS`` links: [host_tx, up1, (up2), (dn1), dn2, host_rx] (-1 = hop
 absent; 2-tier emits the compact 4-hop form).  The engine scatter-adds
-offered rates over these ids (the same computation the linkload Pallas
-kernel implements for the TPU target).
+offered rates over these ids (the same computation the
+``linkload_cascade_tiered`` Pallas kernel implements for the TPU target).
 
 Asymmetry (paper Fig. 8b/11): ``capacity_overrides`` rescales individual
 links — e.g. kill spine 3 and double spine 2's leaf links to 80G.

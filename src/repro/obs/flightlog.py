@@ -15,7 +15,6 @@ than a separate metric taxonomy — the consumers (``obs.trace_export``,
     sweep compile/retry counters, hot uplinks, fault activations, and the
     drained in-sim ring summary under ``insim``
   * ``run_end``   — convergence summary + totals
-  * ``profile``   — benchmarks/run.py --profile phase rows (min/mean/std)
   * ``counter``   — generic named counter sample
 
 Writes are line-buffered and flushed per event; ``read_flight`` tolerates

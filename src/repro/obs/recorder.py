@@ -10,7 +10,7 @@ DCQCN rate quantiles, per-uplink offered-vs-capacity) into a fixed-shape
 ring written with ``dynamic_update_slice`` at ``count % R``.  Fixed shapes
 mean the ring joins the executable-cache key exactly like the traced
 capacity operand (PR 5): one extra compiled program per ``RecordSpec``,
-ZERO rebuilds across epochs — gated by ``scripts/check_bench.py --obs``.
+ZERO rebuilds across epochs — asserted in tests/test_obs.py.
 
 All gating happens at Python trace time: ``record=None`` traces the
 identical program as before recording existed (bit-identical results,

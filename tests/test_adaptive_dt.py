@@ -5,12 +5,13 @@ and the executable-cache build-count contract."""
 import dataclasses
 import hashlib
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.netsim import compact, dcqcn, engine, faults, profile, sweep, \
-    topology, workloads
+from repro.netsim import compact, dcqcn, engine, faults, sweep, topology, \
+    workloads
 
 
 # ------------------------------------------------- seeded-twin goldens
@@ -287,12 +288,29 @@ def test_cache_build_counts_pinned():
 
 # ----------------------------------------------- quiescence profiling
 def test_quiescence_profile_smoke():
+    """Replay the fixed-dt run chunk by chunk through the production
+    shapes and evaluate the adaptive predicate at every boundary, without
+    fast-forwarding: the sparse collective's compute gaps make some
+    chunks quiescent, and the first chunk (arrivals at step 0) is not."""
     topo = topology.leaf_spine(4, 4, 4, 100e9)
     cfg = engine.SimConfig(scheme="seqbalance", duration_s=14e-3,
                            uplink_sample_every=10)
-    q = profile.quiescence_profile(topo, cfg, _collective(topo), iters=3)
-    assert 0.0 < q["ff_fraction"] <= 1.0
-    assert q["predicate_us"] > 0.0
-    covered = sum(k * v for k, v in q["macro_hist"].items())
-    assert covered == round(q["ff_fraction"] * q["n_chunks"]) * q["chunk_steps"]
-    assert q["chunk_steps"] % cfg.uplink_sample_every == 0
+    arrays, _, F = compact.sort_trace(_collective(topo))
+    W, A = compact.plan_single_window(topo, cfg, arrays, F)
+    _, step_fn, phases = compact.build_compact_sim(
+        topo, cfg, tuple(jnp.asarray(a) for a in arrays), W, F, A)
+    n_steps = int(round(cfg.duration_s / cfg.dt))
+    K, n_chunks, _ = compact.plan_chunks(cfg, n_steps)
+    assert K % cfg.uplink_sample_every == 0 and K * n_chunks >= n_steps
+
+    @jax.jit
+    def replay(st):
+        def one(st, _):
+            quiet = phases["quiesce"](st, K)
+            return jax.lax.scan(step_fn, st, None, length=K)[0], quiet
+
+        return jax.lax.scan(one, st, None, length=n_chunks)[1]
+
+    quiet = np.asarray(replay(compact.init_compact_state(topo, cfg, W, F)))
+    assert quiet.shape == (n_chunks,) and not quiet[0]
+    assert 0.0 < quiet.mean() < 1.0

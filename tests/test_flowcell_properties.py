@@ -307,6 +307,40 @@ def test_hetero_steering_lands_flowcells_on_planned_paths():
     assert 2 not in realized  # the quarantined plane carries nothing
 
 
+@pytest.mark.parametrize("fabric", ["symmetric", "hetero"])
+def test_flowcell_spray_wins_only_when_reordering_is_free(fabric):
+    """The paper's no-reordering motivation, quantified on a fabric-bound
+    ring (100G hosts over a 25G fabric, 8 members, 16 MB in 4 chunks):
+    spraying each chunk over every path beats SeqBalance's censored p99
+    only while reordering is free (reorder=None); at a strict go-back-N
+    budget (0 packets) it loses to SeqBalance."""
+    from repro.dist import cosim
+    from repro.netsim import metrics
+
+    if fabric == "symmetric":
+        topo = topology.leaf_spine(4, 4, 4, 25e9, host_bw=100e9)
+    else:
+        topo = topology.hetero_leaf_spine(4, 4, 4, 25e9, 100e9,
+                                          n_fast_spines=1, host_bw=100e9)
+    dur = 10e-3
+    hosts = cosim.ring_hosts(topo, 8)
+    kw = dict(link_bw=25e9, round_gap_s=3e-4, seed=0,
+              steer_paths=topo.n_paths)
+    trace = workloads.collective_trace(_plan(), hosts, 16e6, **kw)
+    sprayed = workloads.collective_trace(_plan(fcells=4), hosts, 16e6, **kw)
+
+    def p99(scheme, tr, reorder):
+        res, _ = sweep.run_one(
+            topo, engine.SimConfig(scheme=scheme, duration_s=dur), tr,
+            reorder=reorder)
+        f, _ = metrics.fct_samples(res, tr, horizon_s=dur)
+        return float(np.percentile(f, 99))
+
+    seq = p99("seqbalance", trace, None)
+    assert p99("ecmp", sprayed, None) < seq
+    assert p99("ecmp", sprayed, 0.0) > seq
+
+
 # ------------------------------------- degenerate sha-golden twin pins
 def test_flowcell_disabled_fig12_bit_identical():
     """The fig12 sweep with the flowcell plumbing in its default state

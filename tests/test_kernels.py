@@ -58,26 +58,29 @@ def test_flash_attention_block_shape_invariance():
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("n,hops,L", [(100, 2, 50), (1000, 6, 200), (513, 4, 300)])
-def test_linkload_sweep(n, hops, L):
-    key = jax.random.PRNGKey(0)
-    ks = jax.random.split(key, 3)
-    lid = jax.random.randint(ks[0], (n, hops), -1, L).astype(jnp.int32)
-    rates = jax.random.uniform(ks[1], (n,)) * 1e9
-    queue = jax.random.uniform(ks[2], (L,)) * 2e6
-    cap = jnp.full((L,), 4e10)
-    l1, q1, m1 = ll.linkload(lid, rates, queue, cap, n_links=L, interpret=True)
-    l2, q2, m2 = ref.linkload_ref(lid, rates, L, 400e3, 1600e3, 0.2, queue, cap, 10e-6)
-    np.testing.assert_allclose(np.asarray(l1), np.asarray(l2), rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(q1), np.asarray(q2), rtol=1e-4, atol=1.0)
-    np.testing.assert_allclose(np.asarray(m1), np.asarray(m2), atol=1e-6)
-
-
-def test_linkload_drop_sentinel():
-    """-1 hops must not contribute anywhere."""
-    lid = jnp.array([[0, -1], [1, -1]], jnp.int32)
-    rates = jnp.array([5.0, 7.0])
-    queue = jnp.zeros((3,))
-    cap = jnp.full((3,), 1e12)
-    l1, _, _ = ll.linkload(lid, rates, queue, cap, n_links=3, interpret=True)
-    np.testing.assert_allclose(np.asarray(l1), [5.0, 7.0, 0.0], atol=1e-6)
+@pytest.mark.parametrize("n_sub", [1, 4])
+def test_linkload_cascade_tiered_absent_hops_add_nothing(n_sub):
+    """-1 fabric hops (an intra-leaf flow, a route with fewer tiers) must
+    not contribute to any link: with capacity to spare, every link's
+    arrival is exactly the rates of the sub-flows that name it."""
+    n, hf, L = 6, 2, 10
+    i = np.arange(n)[:, None]
+    j = np.arange(n_sub)[None, :]
+    fab = np.stack([np.where((i + j) % 2 == 0, 2 + i % 2, -1),
+                    np.where(i < 3, -1, 4 + j % 2)], axis=-1)
+    tx, rx = np.arange(n) % 2, 8 + np.arange(n) % 2
+    rates = (1.0 + i * n_sub + j) * 1e9
+    want = np.zeros(L)
+    np.add.at(want, tx, rates.sum(-1))
+    np.add.at(want, rx, rates.sum(-1))
+    for h in range(hf):
+        hop = fab[..., h]
+        np.add.at(want, hop[hop >= 0], rates[hop >= 0])
+    assert want[6:8].sum() == 0 and (fab == -1).any()
+    args = (jnp.asarray(fab, jnp.int32), jnp.asarray(tx, jnp.int32),
+            jnp.asarray(rx, jnp.int32), jnp.asarray(rates, jnp.float32),
+            jnp.zeros(L), jnp.full((L,), 1e12), jnp.ones(L))
+    arrival, _, _, thr = ll.linkload_cascade_tiered(
+        *args, n_links=L, block_n=128, interpret=True)
+    np.testing.assert_allclose(np.asarray(arrival), want, rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(thr), rates.astype(np.float32))

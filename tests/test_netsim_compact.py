@@ -140,32 +140,18 @@ def test_compact_sampled_uplink_outputs():
 
 
 # ------------------------------------------------ fused dataplane kernels
-@pytest.mark.parametrize("n,hops,L", [(100, 6, 50), (513, 4, 30), (64, 2, 5)])
-def test_linkload_cascade_kernel_vs_ref(n, hops, L):
-    ks = jax.random.split(jax.random.PRNGKey(n), 3)
-    lid = jax.random.randint(ks[0], (n, hops), -1, L).astype(jnp.int32)
-    rates = jax.random.uniform(ks[1], (n,)) * 1e9
-    queue = jax.random.uniform(ks[2], (L,)) * 2e6
-    cap = jnp.full((L,), 4e9)
-    qmask = jnp.ones((L,)).at[:2].set(0.0)
-    a1, q1, m1, t1 = ll.linkload_cascade(
-        lid, rates, queue, cap, qmask, n_links=L, block_n=64, interpret=True
-    )
-    a2, q2, m2, t2 = ref.linkload_cascade_ref(
-        lid, rates, L, 400e3, 1600e3, 0.2, queue, cap, qmask, 10e-6
-    )
-    np.testing.assert_allclose(np.asarray(a1), np.asarray(a2), rtol=2e-5, atol=1e-3)
-    np.testing.assert_allclose(np.asarray(q1), np.asarray(q2), rtol=1e-4, atol=1.0)
-    np.testing.assert_allclose(np.asarray(m1), np.asarray(m2), atol=1e-6)
-    np.testing.assert_allclose(np.asarray(t1), np.asarray(t2), rtol=2e-5, atol=1e-2)
-
-
+# N = 1 (the co-sim's steered ECMP) at the shapes of the flat single-path
+# routes: hops = 2 + hf, and a route with no fabric hop (hf = 1, absent)
 @pytest.mark.parametrize("n,n_sub,hf,L", [(100, 4, 2, 50), (513, 1, 4, 30),
-                                          (64, 2, 2, 5)])
+                                          (64, 2, 2, 5), (100, 1, 4, 50),
+                                          (513, 1, 2, 30), (64, 1, 1, 5),
+                                          (1000, 1, 4, 200), (513, 1, 2, 300)])
 def test_linkload_cascade_tiered_kernel_vs_ref(n, n_sub, hf, L):
     """Interpret-mode check of the NIC-tiered kernel layout."""
     ks = jax.random.split(jax.random.PRNGKey(n), 6)
     fab = jax.random.randint(ks[0], (n, n_sub, hf), -1, L).astype(jnp.int32)
+    if (n_sub, hf) == (1, 1):
+        fab = jnp.full_like(fab, -1)
     tx = jax.random.randint(ks[1], (n,), 0, L).astype(jnp.int32)
     rx = jax.random.randint(ks[2], (n,), 0, L).astype(jnp.int32)
     rates = jax.random.uniform(ks[3], (n, n_sub)) * 1e9
@@ -183,6 +169,20 @@ def test_linkload_cascade_tiered_kernel_vs_ref(n, n_sub, hf, L):
     np.testing.assert_allclose(np.asarray(q1), np.asarray(q2), rtol=1e-4, atol=1.0)
     np.testing.assert_allclose(np.asarray(m1), np.asarray(m2), atol=1e-6)
     np.testing.assert_allclose(np.asarray(t1), np.asarray(t2), rtol=2e-5, atol=1e-2)
+
+
+def flat_cascade(links, rates, queue, capacity, queue_mask, *, n_links, kmin,
+                 kmax, pmax, dt, qmax_bytes):
+    """``ref.linkload_cascade_ref`` over every sub-flow's hop vector, in
+    ``dataplane.cascade_nic``'s layout: a zero sentinel slot appended to
+    the per-link outputs, thr in the rates' shape."""
+    out = ref.linkload_cascade_ref(
+        links.reshape(-1, links.shape[-1]), rates.reshape(-1), n_links, kmin,
+        kmax, pmax, queue[:n_links], capacity[:n_links], queue_mask[:n_links],
+        dt, qmax_bytes)
+    zero = jnp.zeros((1,), jnp.float32)
+    return tuple(jnp.concatenate([x, zero]) for x in out[:3]) + (
+        out[3].reshape(rates.shape),)
 
 
 @pytest.mark.parametrize("kind,seed", [("leaf_spine", 0), ("three_tier", 1),
@@ -216,8 +216,7 @@ def test_cascade_nic_matches_flat(kind, seed):
     qmask = dataplane.queue_mask_for(topo)
     kw = dict(n_links=topo.n_links, kmin=400e3, kmax=1600e3, pmax=0.2,
               dt=10e-6, qmax_bytes=8e6)
-    out_flat = dataplane.cascade(links, rates, queue, topo.capacity, qmask,
-                                 backend="xla", **kw)
+    out_flat = flat_cascade(links, rates, queue, topo.capacity, qmask, **kw)
     out_nic = dataplane.cascade_nic(fab, tx, rx, rates, queue, topo.capacity,
                                     qmask, backend="xla", **kw)
     out_nic_p = dataplane.cascade_nic(fab, tx, rx, rates, queue, topo.capacity,
@@ -236,29 +235,6 @@ def test_cascade_nic_matches_flat(kind, seed):
                                atol=1e-7)
     np.testing.assert_allclose(np.asarray(pf1), np.asarray(pf2), rtol=1e-5,
                                atol=1e-7)
-
-
-def test_dataplane_pallas_backend_matches_xla():
-    """cascade() must give the same answer through the Pallas kernel
-    (interpret mode on CPU) and the XLA segment-sum path."""
-    topo = small_topo()
-    key = jax.random.PRNGKey(7)
-    n = 96
-    src = jax.random.randint(key, (n,), 0, topo.n_hosts)
-    dst = (src + 4) % topo.n_hosts
-    path = jax.random.randint(key, (n,), 0, topo.n_paths)
-    links = topo.subflow_links(src, dst, path)
-    rates = jax.random.uniform(key, (n,)) * 50e9
-    queue = jnp.zeros((topo.n_links + 1,))
-    qmask = dataplane.queue_mask_for(topo)
-    kw = dict(n_links=topo.n_links, kmin=400e3, kmax=1600e3, pmax=0.2,
-              dt=10e-6, qmax_bytes=8e6)
-    out_x = dataplane.cascade(links, rates, queue, topo.capacity, qmask,
-                              backend="xla", **kw)
-    out_p = dataplane.cascade(links, rates, queue, topo.capacity, qmask,
-                              backend="pallas_interpret", **kw)
-    for x, p in zip(out_x, out_p):
-        np.testing.assert_allclose(np.asarray(x), np.asarray(p), rtol=2e-5, atol=1e-2)
 
 
 def test_dense_engine_uses_same_dataplane():

@@ -24,6 +24,7 @@ from hypothesis import example, given, settings, strategies as st  # noqa: E402
 
 from repro.kernels import linkload as ll, ref  # noqa: E402
 from repro.netsim import compact, dataplane, engine, topology, workloads  # noqa: E402
+from tests.test_netsim_compact import flat_cascade  # noqa: E402
 
 
 def _random_instance(seed, n, n_sub, hf, L):
@@ -54,8 +55,7 @@ def test_tiered_cascade_equals_flat(seed, n, n_sub, hf, L):
          jnp.broadcast_to(rx[:, None, None], (n, n_sub, 1))], axis=-1)
     kw = dict(n_links=L, kmin=400e3, kmax=1600e3, pmax=0.2, dt=10e-6,
               qmax_bytes=8e6)
-    out_flat = dataplane.cascade(links, rates, queue, cap, qmask,
-                                 backend="xla", **kw)
+    out_flat = flat_cascade(links, rates, queue, cap, qmask, **kw)
     out_nic = dataplane.cascade_nic(fab, tx, rx, rates, queue, cap, qmask,
                                     backend="xla", **kw)
     tols = [dict(rtol=2e-5, atol=1e-3), dict(rtol=1e-4, atol=1.0),

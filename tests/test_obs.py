@@ -236,21 +236,6 @@ def test_runmeta_keys_stable():
     json.dumps(m)
 
 
-# --------------------------------------------------- profile TimeUs
-def test_time_us_is_float_with_stats():
-    from repro.netsim.profile import TimeUs
-
-    t = TimeUs([3.0, 1.0, 2.0])
-    assert float(t) == 1.0 and t.min_us == 1.0  # min is the headline value
-    assert t.mean_us == pytest.approx(2.0)
-    assert t.std_us == pytest.approx(np.std([3.0, 1.0, 2.0]))
-    assert round(t, 2) == 1.0 and t * 2 == 2.0  # still a float
-    s = t.stats()
-    assert s == dict(min_us=1.0, mean_us=2.0, std_us=round(t.std_us, 3),
-                     iters=3)
-    json.dumps(s)
-
-
 def test_watchdog_transition_counters_roundtrip():
     from repro.dist.elastic import TelemetryWatchdog
 

@@ -191,15 +191,12 @@ def _pallas_names(closed):
     return out
 
 
-@pytest.mark.parametrize("kernel", ["linkload", "linkload_cascade_tiered"])
-def test_pallas_calls_carry_their_names(kernel):
+@pytest.mark.parametrize("n_sub", [pytest.param(4, id="linkload_cascade_tiered"),
+                                   pytest.param(1, id="linkload_cascade_tiered_N1")])
+def test_pallas_calls_carry_their_names(n_sub):
     n, L = 16, 10
-    if kernel == "linkload":
-        fn = functools.partial(ll.linkload, n_links=L, interpret=True)
-        args = (jnp.zeros((n, 2), jnp.int32), jnp.ones(n), jnp.zeros(L), jnp.ones(L))
-    else:
-        fn = functools.partial(ll.linkload_cascade_tiered, n_links=L, interpret=True)
-        args = (jnp.zeros((n, 4, 2), jnp.int32), jnp.zeros(n, jnp.int32),
-                jnp.zeros(n, jnp.int32), jnp.ones((n, 4)), jnp.zeros(L), jnp.ones(L),
-                jnp.ones(L))
-    assert _pallas_names(jax.make_jaxpr(fn)(*args)) == [kernel]
+    fn = functools.partial(ll.linkload_cascade_tiered, n_links=L, interpret=True)
+    args = (jnp.zeros((n, n_sub, 2), jnp.int32), jnp.zeros(n, jnp.int32),
+            jnp.zeros(n, jnp.int32), jnp.ones((n, n_sub)), jnp.zeros(L), jnp.ones(L),
+            jnp.ones(L))
+    assert _pallas_names(jax.make_jaxpr(fn)(*args)) == ["linkload_cascade_tiered"]

@@ -289,6 +289,35 @@ def test_cosim_blackout_trips_safe_mode_and_recovers(small_topo):
     assert h.records[-1].completion >= 1.0
 
 
+@pytest.mark.parametrize("delay", [0, 1, 2])
+def test_cosim_lossy_telemetry_reconverges_near_lossless(delay, small_topo):
+    """Congestion reports over a lossy channel (10 % and 30 % loss) at a
+    given delay: the planner reconverges within one epoch of the lossless
+    channel at the same delay, and the delay alone costs at most its own
+    epochs against a perfect channel.  Plan versions stay strictly
+    monotone and no newer plan is refused, whatever the channel drops."""
+    from repro.dist import cosim
+
+    topo = small_topo
+    hosts = cosim.ring_hosts(topo, 4)
+    kw = dict(scheme="ecmp", epochs=8, phi_steps=2, n_chunks=8, seed=0,
+              staleness_bound=2,
+              faults=(cosim.kill_spine(topo, 1, epoch=1, recover_epoch=5),))
+    conv = {}
+    for loss in (0.0, 0.1, 0.3):
+        h = cosim.run_cosim(topo, hosts, 2e6, telemetry=TelemetryChannel(
+            loss=loss, delay_epochs=delay, seed=7), **kw)
+        vs = [r.plan_version for r in h.records]
+        assert all(b > a for a, b in zip(vs, vs[1:])), (loss, vs)
+        assert h.plan_refused == 0
+        conv[loss] = h.convergence_epoch(1)
+        assert conv[loss] is not None, (loss, delay)
+    perfect = cosim.run_cosim(topo, hosts, 2e6, telemetry=TelemetryChannel(),
+                              **kw).convergence_epoch(1)
+    assert conv[0.0] <= perfect + delay, (conv, perfect)
+    assert conv[0.1] <= conv[0.0] + 1 and conv[0.3] <= conv[0.0] + 1, conv
+
+
 def test_cosim_journal_schema_v2_and_refusal(tmp_path, small_topo):
     from repro.dist import cosim
 
